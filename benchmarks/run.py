@@ -25,6 +25,7 @@ from benchmarks import (fig10_steal_traffic, kernel_micro, roofline_table,
                         service_latency, service_throughput,
                         table1_vertex_cover, table2_dominating_set)
 from benchmarks.common import ART_DIR
+from repro import compile_cache
 
 SUITES = [
     ("table1", table1_vertex_cover.main),
@@ -68,6 +69,7 @@ def main() -> None:
     # Anything after `--` is forwarded to the selected suite's own CLI,
     # e.g. `python -m benchmarks.run --only service -- --devices 1,2,4`.
     args, extra = ap.parse_known_args()
+    compile_cache.enable()
     if extra and extra[0] == "--":
         extra = extra[1:]
     if extra and not args.only:

@@ -161,9 +161,9 @@ def run(quick: bool = False) -> dict:
 
 # -- mesh device axis (DESIGN.md §9) -----------------------------------------
 
-def _axis_child(devices, quick: bool) -> None:
-    """Subprocess body: replay the trace per device count (priority
-    policy); the parent forced the host device count before spawning."""
+def _device_legs(devices, quick: bool) -> dict:
+    """Replay the trace per device count (priority policy) on
+    ``jax.devices()[:d]``, in this process."""
     import jax
     trace = poisson_trace(quick)
     oracles = {r.rid: Solver().oracle(registry.problem(r.family,
@@ -183,29 +183,49 @@ def _axis_child(devices, quick: bool) -> None:
                         "p95_latency_rounds": rep["p95_latency_rounds"],
                         "deadline_hit_rate": rep["deadline_hit_rate"],
                         "completed": rep["completed"]}
-    print("DEVICES_RESULT " + json.dumps(legs))
+    return legs
+
+
+def _axis_child(devices, quick: bool) -> None:
+    """Subprocess body on the CPU platform: the parent forced the host
+    device count before spawning us."""
+    print("DEVICES_RESULT " + json.dumps(_device_legs(devices, quick)))
 
 
 def run_devices(devices, quick: bool) -> dict:
-    """Spawn the device-axis subprocess; scaling asserted on the total
-    rounds-to-drain of the priority replay (latency percentiles are
-    recorded context — the easy traffic is already near the floor)."""
+    """Run the device legs; scaling asserted on the total rounds-to-drain
+    of the priority replay (latency percentiles are recorded context —
+    the easy traffic is already near the floor).
+
+    Where this process already sees enough devices the legs run
+    in-process on ``jax.devices()[:d]`` (a parent holding a chip cannot
+    hand it to a child); only on the CPU platform does it spawn a child
+    with forced host devices.
+    """
+    import jax
+
     devices = sorted(set(devices))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                        f"{max(devices + [2])}")
-    cmd = [sys.executable, "-m", "benchmarks.service_latency",
-           "--_axis-child", ",".join(str(d) for d in devices)]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=3600,
-                          cwd=os.path.dirname(os.path.dirname(
-                              os.path.abspath(__file__))))
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    line = [l for l in proc.stdout.splitlines()
-            if l.startswith("DEVICES_RESULT ")][-1]
-    legs = json.loads(line[len("DEVICES_RESULT "):])
+    if len(jax.devices()) >= max(devices):
+        legs = _device_legs(devices, quick)
+    elif jax.default_backend() == "cpu":
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+                            f"{max(devices + [2])}")
+        cmd = [sys.executable, "-m", "benchmarks.service_latency",
+               "--_axis-child", ",".join(str(d) for d in devices)]
+        if quick:
+            cmd.append("--quick")
+        proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                              timeout=3600,
+                              cwd=os.path.dirname(os.path.dirname(
+                                  os.path.abspath(__file__))))
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        line = [l for l in proc.stdout.splitlines()
+                if l.startswith("DEVICES_RESULT ")][-1]
+        legs = json.loads(line[len("DEVICES_RESULT "):])
+    else:
+        raise SystemExit(f"--devices {max(devices)} needs that many devices;"
+                         f" {jax.default_backend()} has {len(jax.devices())}")
     axis = {
         "unit": "priority-policy replay; rounds are hardware-neutral, "
                 "wider pools must drain in fewer total rounds",

@@ -175,14 +175,13 @@ AX_LANES = 8
 AX_STEPS = 8
 
 
-def _axis_child(devices, quick: bool) -> None:
-    """Subprocess body: run every device leg under forced host devices.
+def _device_legs(devices, quick: bool) -> dict:
+    """Every device leg on ``jax.devices()[:d]``, in this process.
 
-    The parent sets XLA_FLAGS before spawning us; jax locks the device
-    count at first init, so all legs share one process and one mix.  A
-    ``pre_shard`` leg at the MAIN config with mesh=None (the plain jit
-    path) is emitted alongside: it is the identical deterministic
-    computation to the parent's in-process service leg and gates on it.
+    All legs share one process and one mix.  A ``pre_shard`` leg at the
+    MAIN config with mesh=None (the plain jit path) is emitted alongside:
+    it is the identical deterministic computation to the in-process
+    service leg and gates on it.
     """
     import jax
     mix = instance_mix(quick)
@@ -201,13 +200,25 @@ def _axis_child(devices, quick: bool) -> None:
                         "total_lanes": AX_LANES * d, "rounds": rounds,
                         "wall_s": round(wall, 3),
                         "instances_per_sec": round(k / wall, 3)}
-    print("DEVICES_RESULT " + json.dumps(
-        {"pre_shard": {"rounds": rounds0, "wall_s": round(wall0, 3)},
-         "legs": legs}))
+    return {"pre_shard": {"rounds": rounds0, "wall_s": round(wall0, 3)},
+            "legs": legs}
+
+
+def _axis_child(devices, quick: bool) -> None:
+    """Subprocess body on the CPU platform: the parent sets XLA_FLAGS to
+    force host devices before spawning us (jax fixes the device count at
+    first init)."""
+    print("DEVICES_RESULT " + json.dumps(_device_legs(devices, quick)))
 
 
 def run_devices(devices, quick: bool, baseline: dict = None) -> dict:
-    """Spawn the device-axis subprocess, check scaling, -> merged section.
+    """Run the device legs, check scaling, -> merged section.
+
+    Where this process already sees enough devices (a chip host, or a CPU
+    started with forced host devices) the legs run in-process on
+    ``jax.devices()[:d]``: a parent that holds a chip cannot hand it to a
+    child.  Only on the CPU platform does it spawn a child with forced
+    host devices.
 
     Scaling is asserted on rounds-to-drain (forced host devices share the
     same CPU cores, so wall-clock is context, not a claim): every d > 1
@@ -216,22 +227,30 @@ def run_devices(devices, quick: bool, baseline: dict = None) -> dict:
     round count — same deterministic computation, so sharding-off must be
     exactly the pre-shard service.
     """
+    import jax
+
     devices = sorted(set(devices))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                        f"{max(devices + [2])}")
-    cmd = [sys.executable, "-m", "benchmarks.service_throughput",
-           "--_axis-child", ",".join(str(d) for d in devices)]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=3600,
-                          cwd=os.path.dirname(os.path.dirname(
-                              os.path.abspath(__file__))))
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    line = [l for l in proc.stdout.splitlines()
-            if l.startswith("DEVICES_RESULT ")][-1]
-    res = json.loads(line[len("DEVICES_RESULT "):])
+    if len(jax.devices()) >= max(devices):
+        res = _device_legs(devices, quick)
+    elif jax.default_backend() == "cpu":
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+                            f"{max(devices + [2])}")
+        cmd = [sys.executable, "-m", "benchmarks.service_throughput",
+               "--_axis-child", ",".join(str(d) for d in devices)]
+        if quick:
+            cmd.append("--quick")
+        proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                              timeout=3600,
+                              cwd=os.path.dirname(os.path.dirname(
+                                  os.path.abspath(__file__))))
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        line = [l for l in proc.stdout.splitlines()
+                if l.startswith("DEVICES_RESULT ")][-1]
+        res = json.loads(line[len("DEVICES_RESULT "):])
+    else:
+        raise SystemExit(f"--devices {max(devices)} needs that many devices;"
+                         f" {jax.default_backend()} has {len(jax.devices())}")
     legs, pre = res["legs"], res["pre_shard"]
     axis = {
         "unit": "rounds-to-drain (hardware-neutral; forced host devices "

@@ -11,7 +11,7 @@ outright or, worse, silently re-traces / re-syncs every round.
 The pass works in three stages, all purely static:
 
 1. **Traced-context discovery.**  Any function object passed to a
-   tracing primitive (``jax.jit``, ``compat.shard_map``, ``jax.vmap``,
+   tracing primitive (``jax.jit``, ``jax.shard_map``, ``jax.vmap``,
    ``jax.lax.while_loop/fori_loop/cond/scan/switch``,
    ``pl.pallas_call``, ``pl.when`` — call or decorator form, including
    ``partial(jax.jit, ...)``) is traced.  Builders are propagated one

@@ -41,12 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
-
 from repro.core.api import UNVISITED, BinaryProblem
 from repro.core import steal
-from repro.core.engine import Lanes, init_lanes, make_expand
+from repro.core.engine import (Lanes, init_lanes, instance_onehot,
+                               make_expand)
 
 
 class SolveStats(NamedTuple):
@@ -64,7 +62,7 @@ def _axis_rank(axis_names: Sequence[str]) -> jnp.ndarray:
     """Linearized device rank over (possibly multiple) mesh axes."""
     rank = jnp.int32(0)
     for name in axis_names:
-        rank = rank * compat.axis_size(name) + jax.lax.axis_index(name)
+        rank = rank * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return rank
 
 
@@ -89,10 +87,10 @@ def cross_device_steal(problem: BinaryProblem, lanes: Lanes,
     thieves = steal.thief_mask(lanes)
     slots = steal.donor_slots(lanes)
     donors = steal.donor_mask(lanes, slots)
-    demand_local = jnp.zeros((k,), jnp.int32).at[safe_inst].add(
-        thieves.astype(jnp.int32))
-    donatable = jnp.zeros((k,), jnp.int32).at[safe_inst].add(
-        donors.astype(jnp.int32))
+    mine = instance_onehot(safe_inst, k)                        # [W, K]
+    demand_local = jnp.sum(mine & thieves[:, None], axis=0,
+                           dtype=jnp.int32)
+    donatable = jnp.sum(mine & donors[:, None], axis=0, dtype=jnp.int32)
 
     # (1) advertise; all_gather along the flattened mesh axes.
     summary = jnp.stack([demand_local, donatable], axis=1)      # [K, 2]
@@ -182,7 +180,8 @@ def make_round(problem: BinaryProblem, steps_per_round: int,
         contrib = (lanes.active.astype(jnp.int32)
                    + (lanes.active
                       & (slots < lanes.idx.shape[1])).astype(jnp.int32))
-        open_work = jnp.zeros((k,), jnp.int32).at[safe_inst].add(contrib)
+        open_work = jnp.sum(jnp.where(instance_onehot(safe_inst, k),
+                                      contrib[:, None], 0), axis=0)
         if axis_names:
             open_work = jax.lax.psum(open_work, tuple(axis_names))
         return lanes, open_work
@@ -215,8 +214,8 @@ def make_distributed_round(problem: BinaryProblem, mesh: Mesh,
     round_fn = make_round(problem, steps_per_round, axes, max_ship,
                           fused_steps)
     in_specs = lane_partition_specs(problem, axes)
-    fn = shard_map(round_fn, mesh=mesh, in_specs=(in_specs,),
-                   out_specs=(in_specs, P()), check=False)
+    fn = jax.shard_map(round_fn, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=(in_specs, P()), check_vma=False)
     return jax.jit(fn)
 
 

@@ -126,14 +126,43 @@ def init_lanes(problem: BinaryProblem, num_lanes: int,
     )
 
 
+def _at(n: int, pos, ndim: int) -> jnp.ndarray:
+    """bool one-hot of ``pos`` along a leading axis of length ``n``,
+    shaped to broadcast against an ``ndim``-dimensional array."""
+    hot = jnp.arange(n, dtype=jnp.int32) == pos
+    return hot.reshape((n,) + (1,) * (ndim - 1))
+
+
+def read_at(s: jnp.ndarray, pos) -> jnp.ndarray:
+    """``s[pos]`` along axis 0 (``pos`` in range) as a one-hot reduction.
+
+    Per-lane reads and writes at a data-dependent depth are dense selects
+    rather than dynamic indexing.  Vmapped over lanes, dynamic indexing
+    becomes a gather/scatter over the lane axis.  On a TPU v5e those
+    scatters cost time in proportion to the lane count, and two of them
+    writing neighbouring int8 slots of one ``idx`` row lost the first
+    write at 1024 lanes, so the search left the CPU's (DESIGN.md §2).
+    A select over the whole axis is plain vector work."""
+    hot = _at(s.shape[0], pos, s.ndim)
+    if s.dtype == jnp.bool_:
+        return jnp.any(hot & s, axis=0)
+    return jnp.sum(jnp.where(hot, s, jnp.zeros((), s.dtype)), axis=0,
+                   dtype=s.dtype)
+
+
+def write_at(s: jnp.ndarray, pos, value, when=True) -> jnp.ndarray:
+    """``s`` with ``s[pos] = value`` where ``when`` (``pos`` in range)."""
+    return jnp.where(_at(s.shape[0], pos, s.ndim) & when,
+                     jnp.asarray(value, s.dtype)[None], s)
+
+
 def _select_node(idx, depth, stack):
-    """Gather ONE lane's current node state off its stack (vmapped by
+    """Read ONE lane's current node state off its stack (vmapped by
     ``make_step`` — the select half of the select/evaluate/advance split
     that lets ``evaluate_batch`` see all lanes in one call)."""
     il = idx.shape[0]
     d = jnp.clip(depth, 0, il - 1)
-    state = jax.tree_util.tree_map(
-        lambda s: jax.lax.dynamic_index_in_dim(s, d, keepdims=False), stack)
+    state = jax.tree_util.tree_map(lambda s: read_at(s, d), stack)
     return state, d
 
 
@@ -148,7 +177,7 @@ def _advance_lane(idx, depth, base, active, stack, best, ev, d):
     one evaluation backs one node visit.
     """
     il = idx.shape[0]
-    c = idx[d]
+    c = read_at(idx, d)
     first = c == UNVISITED
     is_sol, val, lb = ev.is_solution, ev.value, ev.lower_bound
 
@@ -164,20 +193,15 @@ def _advance_lane(idx, depth, base, active, stack, best, ev, d):
 
     wpos = jnp.clip(d + 1, 0, il)  # stack has one extra slot
     new_stack = jax.tree_util.tree_map(
-        lambda s, ch: jax.lax.dynamic_update_index_in_dim(
-            s,
-            jnp.where(descend, ch,
-                      jax.lax.dynamic_index_in_dim(s, wpos, keepdims=False)),
-            wpos, axis=0),
-        stack, child)
+        lambda s, ch: write_at(s, wpos, ch, descend), stack, child)
 
     # current_idx maintenance (paper Fig. 3, line 4).
     slot_now = jnp.where(descend & first, LEFT,
                          jnp.where(descend & take_right, RIGHT, c))
-    new_idx = idx.at[d].set(jnp.where(active, slot_now, c))
+    new_idx = write_at(idx, d, jnp.where(active, slot_now, c))
     # Fresh child slot starts UNVISITED.
-    child_slot = jnp.where(descend, UNVISITED, new_idx[jnp.clip(d + 1, 0, il - 1)])
-    new_idx = new_idx.at[jnp.clip(d + 1, 0, il - 1)].set(child_slot)
+    new_idx = write_at(new_idx, jnp.clip(d + 1, 0, il - 1), UNVISITED,
+                       descend)
 
     new_depth = jnp.where(active, jnp.where(descend, depth + 1, depth - 1), depth)
     new_active = active & (new_depth >= base)
@@ -186,6 +210,12 @@ def _advance_lane(idx, depth, base, active, stack, best, ev, d):
     visited = active & first
     return (new_idx, new_depth, new_active, new_stack, visited,
             improved, jnp.where(improved, val, INF_VALUE), ev.payload)
+
+
+def instance_onehot(inst: jnp.ndarray, k: int) -> jnp.ndarray:
+    """bool[W, K]: lane ``l`` serves instance ``i`` — the dense form of a
+    per-instance segment reduction (no scatter over the lane axis)."""
+    return inst[:, None] == jnp.arange(k, dtype=inst.dtype)[None, :]
 
 
 def make_step(problem: BinaryProblem):
@@ -220,12 +250,13 @@ def make_step(problem: BinaryProblem):
         # Incumbent election per instance (the paper's broadcast, free
         # here): segment-min of the improved values over ``inst``, then the
         # lowest-id winning lane supplies the payload for its instance.
-        seg = jnp.full((k,), INF_VALUE, jnp.int32).at[safe_inst].min(vals)
+        mine = instance_onehot(safe_inst, k)                    # [W, K]
+        seg = jnp.min(jnp.where(mine, vals[:, None], INF_VALUE), axis=0)
         any_improved = seg < lanes.best
         new_best = jnp.minimum(lanes.best, seg)
         lane_ids = jnp.arange(w, dtype=jnp.int32)
-        winner = jnp.full((k,), w, jnp.int32).at[safe_inst].min(
-            jnp.where(improved & (vals == seg[safe_inst]), lane_ids, w))
+        wins = mine & (improved & (vals == seg[safe_inst]))[:, None]
+        winner = jnp.min(jnp.where(wins, lane_ids[:, None], w), axis=0)
         safe_winner = jnp.clip(winner, 0, w - 1)
 
         def elect(p, old):

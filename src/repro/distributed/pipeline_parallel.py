@@ -70,11 +70,10 @@ def pipeline_forward(stage_fn: Callable[[PyTree, jnp.ndarray], jnp.ndarray],
         outs = jax.lax.all_gather(outs, stage_axis)[s - 1]
         return outs
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(stage_axis),
                                          params_stacked),
                   P()),
-        out_specs=P(), check=False)
+        out_specs=P(), check_vma=False)
     return fn(params_stacked, x_mb)

@@ -9,9 +9,8 @@ Layout (DESIGN.md §5):
                           family binds to;
   ``bitset_degree.py``  — vertex cover's binding of that library;
   ``flash_attention.py``/``ssd_scan.py`` — model-side kernels;
-  ``ops.py``            — jitted dispatchers (Pallas on TPU, jnp oracle
-                          elsewhere, interpret-mode for off-TPU kernel
-                          execution);
+  ``ops.py``            — jitted dispatchers (Pallas off the CPU, jnp
+                          oracle on it; interpret mode on the CPU only);
   ``ref.py``            — the pure-jnp oracles each kernel is validated
                           against.
 """

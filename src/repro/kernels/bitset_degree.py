@@ -10,7 +10,7 @@ set, so this module is a thin argument adapter — the kernel body, grid and
 block shapes live in ``bitset_ops`` and are documented in DESIGN.md
 §5.1/§5.5; the per-column contract is §5.2.  ``tile``/``stages`` default
 to the per-shape autotuner (DESIGN.md §5.6) and ``interpret=None``
-compiles on TPU / interprets elsewhere.
+interprets on the CPU only and compiles everywhere else.
 
 Kept as a module (rather than folding the call sites into
 ``problems/vertex_cover.py``) so the kernel library's problem bindings

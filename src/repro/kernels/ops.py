@@ -1,8 +1,10 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU (``jax.default_backend() == "tpu"``) the Pallas kernels compile
-natively; elsewhere the pure-jnp oracles run (CPU smoke/benchmarks) and
-``interpret=True`` executes the kernel bodies for correctness tests.
+``use_pallas=None`` runs the Pallas kernel on every platform but the CPU,
+where the pure-jnp oracle runs instead (CPU smoke/benchmarks).  A kernel
+that does run interprets only on the CPU platform or when a caller passes
+``interpret=True`` (``autotune.resolve_interpret``); on a device it
+compiles or the call raises.
 """
 
 from __future__ import annotations
@@ -14,14 +16,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import bitset_ops, ref
+from repro.kernels.autotune import resolve_interpret
 from repro.kernels.bitset_degree import degree_argmax as _degree_pallas
 from repro.kernels.bitset_degree import degree_stats as _degree_stats_pallas
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _use_pallas(use_pallas: Optional[bool]) -> bool:
+    if use_pallas is None:
+        return jax.default_backend() != "cpu"
+    return use_pallas
 
 
 @partial(jax.jit, static_argnames=("window", "softcap", "query_scale",
@@ -33,13 +38,11 @@ def flash_attention(q, k, v, *, window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
                     use_pallas: Optional[bool] = None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if _use_pallas(use_pallas):
         return _flash_pallas(q, k, v, window=window, softcap=softcap,
                              query_scale=query_scale, block_q=block_q,
                              block_k=block_k,
-                             interpret=(not _on_tpu()) if interpret is None
-                             else interpret)
+                             interpret=resolve_interpret(interpret))
     return ref.flash_attention_ref(q, k, v, window=window, softcap=softcap,
                                    query_scale=query_scale,
                                    block_q=block_q, block_k=block_k)
@@ -50,11 +53,9 @@ def ssd_scan(x, dt, a, b, c, d, *, chunk: int = 64,
              use_pallas: Optional[bool] = None,
              interpret: Optional[bool] = None
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if _use_pallas(use_pallas):
         return _ssd_pallas(x, dt, a, b, c, d, chunk=chunk,
-                           interpret=(not _on_tpu()) if interpret is None
-                           else interpret)
+                           interpret=resolve_interpret(interpret))
     return ref.ssd_scan_ref(x, dt, a, b, c, d, chunk=chunk)
 
 
@@ -66,8 +67,7 @@ def degree_stats(adj, alive, *, tile: Optional[int] = None,
                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """(best_degree, best_vertex, degree_sum) per lane — the fused
     vertex-cover node statistics (see problems.vertex_cover)."""
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if _use_pallas(use_pallas):
         return _degree_stats_pallas(adj, alive, tile=tile, stages=stages,
                                     interpret=interpret)
     return ref.degree_stats_ref(adj, alive)
@@ -79,8 +79,7 @@ def degree_argmax(adj, alive, *, tile: Optional[int] = None,
                   stages: Optional[int] = None,
                   use_pallas: Optional[bool] = None,
                   interpret: Optional[bool] = None) -> jnp.ndarray:
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    if _use_pallas(use_pallas):
         return _degree_pallas(adj, alive, tile=tile, stages=stages,
                               interpret=interpret)
     return ref.degree_argmax_ref(adj, alive)
@@ -88,11 +87,10 @@ def degree_argmax(adj, alive, *, tile: Optional[int] = None,
 
 def _dispatch(pallas_fn, ref_fn, args, *, use_pallas, interpret,
               kernel_kw=None, ref_kw=None):
-    """Shared backend resolution for the bitset_ops dispatchers: Pallas on
-    TPU (or when forced), jnp oracle elsewhere; ``interpret=None`` is
-    resolved by the kernel itself (compiled on TPU, interpret off-TPU)."""
-    use = _on_tpu() if use_pallas is None else use_pallas
-    if use:
+    """Shared backend resolution for the bitset_ops dispatchers: Pallas off
+    the CPU (or when forced), jnp oracle on the CPU; ``interpret=None`` is
+    resolved by the kernel itself (interpret on the CPU only)."""
+    if _use_pallas(use_pallas):
         return pallas_fn(*args, interpret=interpret, **(kernel_kw or {}))
     return ref_fn(*args, **(ref_kw or {}))
 

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import jax
 
+from repro import peaks
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -25,8 +27,10 @@ def make_host_mesh(model: int = 1):
     return jax.make_mesh((data, model), ("data", "model"))
 
 
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link (~per chip per hop)
-HBM_BYTES = 16 * 2 ** 30          # 16 GB per chip
+# Per-chip peaks of the production chip used by the roofline analysis —
+# read from the one peak table, ``repro.peaks`` (keyed by device_kind).
+_V5E = peaks.peaks_for("TPU v5 lite")
+PEAK_FLOPS_BF16 = _V5E.bf16_flops
+HBM_BW = _V5E.hbm_bytes_per_s
+ICI_BW = _V5E.ici_bytes_per_s
+HBM_BYTES = _V5E.hbm_bytes

@@ -38,7 +38,7 @@ from __future__ import annotations
 import argparse
 import time
 
-from repro import registry
+from repro import compile_cache, registry
 from repro.service import SCHEDULERS, SolveRequest, SolverService
 from repro.solver import Solver, SolverConfig
 
@@ -118,6 +118,7 @@ def main() -> None:
     ap.add_argument("--metrics", action="store_true",
                     help="collect in-process metrics and print a summary")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.resume and not args.ckpt:
         ap.error("--resume requires --ckpt")

@@ -17,7 +17,7 @@ from __future__ import annotations
 import argparse
 import time
 
-from repro import registry
+from repro import compile_cache, registry
 from repro.problems.graphs import parse_graph_instance
 from repro.solver import Solver, SolverConfig
 
@@ -49,6 +49,7 @@ def main() -> None:
     ap.add_argument("--metrics", action="store_true",
                     help="collect in-process metrics and print a summary")
     args = ap.parse_args()
+    compile_cache.enable()
 
     # Capability check is registry data, not per-problem branching
     # (DESIGN.md §5.4/§6): a problem gains --backend pallas the moment its

@@ -186,11 +186,10 @@ class Shardings:
             dp_n *= sizes_[a]
 
         def build(token_spec):
-            from repro.compat import shard_map
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(token_spec, pspecs),
-                out_specs=token_spec, check=False)
+                out_specs=token_spec, check_vma=False)
 
         sharded = build(P(dp if dp else None, None))
         replicated = build(P(None, None))

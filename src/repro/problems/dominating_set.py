@@ -20,7 +20,7 @@ bound and both children, through a pluggable ``stats_fn``:
   backend="pallas"  — ``repro.kernels.bitset_ops.domination_stats``, the
                       universal masked-popcount kernel bound with
                       mask = the undominated set and valid = the candidate
-                      set (DESIGN.md §5.2/§5.4; interpret-mode off-TPU).
+                      set (DESIGN.md §5.2/§5.4; interpret mode on the CPU only).
 
 Both backends are bitwise-identical — same coverage counts, same
 smallest-id tie-break, same bound — so the search tree is invariant under

@@ -24,8 +24,8 @@ pluggable ``stats_fn``:
   backend="pallas"  — ``repro.kernels.bitset_degree.degree_stats``, the
                       universal masked-popcount kernel of
                       ``repro.kernels.bitset_ops`` bound with mask = valid
-                      = the alive set (DESIGN.md §5.2/§5.4;
-                      interpret-mode off-TPU); vmap over lanes lifts into
+                      = the alive set (DESIGN.md §5.2/§5.4; interpret
+                      mode on the CPU only); vmap over lanes lifts into
                       an extra grid dimension.
 
 Both backends are bitwise-identical (same degrees, same smallest-id

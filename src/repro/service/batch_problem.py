@@ -34,7 +34,7 @@ same seam as the single-instance problems):
                       masked-popcount kernel: each lane's table block is
                       selected by instance id via scalar prefetch, so the
                       kernel never touches the other K-1 tables
-                      (DESIGN.md §5.3; interpret-mode off-TPU).
+                      (DESIGN.md §5.3; interpret mode on the CPU only).
 
 ``StackedTables`` is runtime DATA, not a trace-time constant: the service
 driver passes it as an argument to the jitted round, so admitting a new

@@ -64,13 +64,13 @@ from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat, registry
+from repro import registry
 from repro.core import checkpoint as ckpt
 from repro.core.api import INF_VALUE, UNVISITED
-from repro.core.distributed import (_gather_lanes, lane_partition_specs,
-                                    make_round)
+from repro.core.distributed import (_gather_lanes, _shard_lanes,
+                                    lane_partition_specs, make_round)
 from repro.core.engine import NO_INSTANCE, init_lanes
 from repro.problems.graphs import Graph, num_words
 from repro.service.batch_problem import StackedSpec, StackedTables
@@ -178,9 +178,10 @@ class SolverService:
         self.num_lanes = num_lanes * self.n_devices
         self._build_round_fns()
 
-        proto = self.spec.bind(self._tables_jnp())
-        self.lanes = init_lanes(proto, self.num_lanes, seed_root=False,
-                                bind_instance=False)
+        proto = self.spec.bind(self._tables_host())
+        self.lanes = self._place(init_lanes(proto, self.num_lanes,
+                                            seed_root=False,
+                                            bind_instance=False))
 
         policy = (scheduler if not isinstance(scheduler, str)
                   else make_policy(scheduler))
@@ -213,34 +214,10 @@ class SolverService:
     def _build_round_fns(self) -> None:
         """(Re)jit the round + stack-rebuild closures for the current mesh
         — called at construction and after every :meth:`resize`."""
-        spec, backend = self.spec, self.backend
-        steps, fused = self.steps_per_round, self.fused_steps
-        mesh = self.mesh
-
-        def _rebuild(lanes, tables):
-            return ckpt.rebuild_stacks(spec.bind(tables, backend), lanes)
-
-        self._rebuild = jax.jit(_rebuild)
-        if mesh is None:
-            def _round(lanes, tables):
-                return make_round(spec.bind(tables, backend), steps,
-                                  fused_steps=fused)(lanes)
-
-            self._round = jax.jit(_round)
-            return
-        axes = tuple(mesh.axis_names)
-        max_ship = self.max_ship
-
-        def _round(lanes, tables):
-            return make_round(spec.bind(tables, backend), steps, axes,
-                              max_ship, fused)(lanes)
-
-        lane_specs = lane_partition_specs(
-            spec.bind(self._tables_jnp(), backend), axes)
-        table_specs = StackedTables(P(), P(), P())    # replicated per device
-        self._round = jax.jit(compat.shard_map(
-            _round, mesh=mesh, in_specs=(lane_specs, table_specs),
-            out_specs=(lane_specs, P()), check=False))
+        self._round, self._rebuild = make_service_round_fns(
+            self.spec, self.backend, self.steps_per_round,
+            fused_steps=self.fused_steps, mesh=self.mesh,
+            max_ship=self.max_ship)
 
     def metrics(self):
         """``repro.obs.MetricsSnapshot`` of this service's registry, or
@@ -261,11 +238,22 @@ class SolverService:
 
     # -- host/device plumbing ----------------------------------------------
 
+    def _tables_host(self) -> StackedTables:
+        """Uncommitted device copies, for eager host-side surgery."""
+        return StackedTables(*(jnp.asarray(t) for t in self.tables))
+
     def _tables_jnp(self) -> StackedTables:
         if self._tables_dev is None:
-            self._tables_dev = StackedTables(
-                *(jnp.asarray(t) for t in self.tables))
+            tables = self._tables_host()
+            if self.mesh is not None:             # replicated per device
+                tables = jax.device_put(tables, NamedSharding(self.mesh, P()))
+            self._tables_dev = tables
         return self._tables_dev
+
+    def _place(self, lanes):
+        """Lay host-written lane state out over the mesh (lanes sharded,
+        incumbents replicated); a no-op without a mesh."""
+        return lanes if self.mesh is None else _shard_lanes(lanes, self.mesh)
 
     def _touch_tables(self) -> None:
         self._tables_dev = None
@@ -486,14 +474,14 @@ class SolverService:
             # tenant steady state can skip even the validation readback.
             self._placement_clean = len(live) <= 1
             return False                 # steady state: no host->device copy
-        self.lanes = self.lanes._replace(
+        self.lanes = self._place(self.lanes._replace(
             idx=jnp.asarray(h["idx"]), depth=jnp.asarray(h["depth"]),
             base=jnp.asarray(h["base"]), inst=jnp.asarray(h["inst"]),
             active=jnp.asarray(h["active"]), t_s=jnp.asarray(h["t_s"]),
             best=jnp.asarray(h["best"]),
             best_payload=(self.lanes.best_payload if payload_host is None
                           else jax.tree_util.tree_map(jnp.asarray,
-                                                      payload_host)))
+                                                      payload_host))))
         if changed:
             # CONVERTINDEX replay rebuilds the stacks of seeded/installed
             # lanes (replaying untouched active lanes is a no-op by the
@@ -533,7 +521,8 @@ class SolverService:
                 h_inst = np.asarray(self.lanes.inst).copy()
             h_inst[h_inst == slot] = NO_INSTANCE
         if h_inst is not None:
-            self.lanes = self.lanes._replace(inst=jnp.asarray(h_inst))
+            self.lanes = self._place(
+                self.lanes._replace(inst=jnp.asarray(h_inst)))
             self._placement_clean = False
 
     def _evict_slot(self, slot: int, status: str) -> RequestResult:
@@ -563,8 +552,8 @@ class SolverService:
         mine = inst == slot
         active[mine] = False
         inst[mine] = NO_INSTANCE
-        self.lanes = self.lanes._replace(inst=jnp.asarray(inst),
-                                         active=jnp.asarray(active))
+        self.lanes = self._place(self.lanes._replace(
+            inst=jnp.asarray(inst), active=jnp.asarray(active)))
         self.pool = [t for t in self.pool if t.inst != slot]
         return result
 
@@ -710,14 +699,15 @@ class SolverService:
         if total < 1:
             raise ValueError(f"resize to {total} lanes")
         old_dev, old_total = self.n_devices, self.num_lanes
-        problem = self.spec.bind(self._tables_jnp(), self.backend)
+        problem = self.spec.bind(self._tables_host(), self.backend)
         lanes_host = _gather_lanes(self.lanes)
         new_lanes, surplus = ckpt.repartition(problem, lanes_host, total)
         self.mesh = mesh
         self.n_devices = n_dev
         self.lanes_per_device = per_dev
         self.num_lanes = total
-        self.lanes = new_lanes
+        self._touch_tables()                   # re-place on the new mesh
+        self.lanes = self._place(new_lanes)
         self.pool.extend(surplus)
         self._placement_clean = False
         self._build_round_fns()
@@ -866,8 +856,9 @@ class SolverService:
             adj=extra["adj"].copy(), fullm=extra["fullm"].copy(),
             family=extra["family"].copy())
         svc._touch_tables()
-        problem = svc.spec.bind(svc._tables_jnp(), backend)
-        svc.lanes, svc.pool = ckpt.restore(path, problem, svc.num_lanes)
+        problem = svc.spec.bind(svc._tables_host(), backend)
+        lanes, svc.pool = ckpt.restore(path, problem, svc.num_lanes)
+        svc.lanes = svc._place(lanes)
         for i in range(extra["pool_idx"].shape[0]):
             d, b, inst = (int(x) for x in extra["pool_meta"][i])
             svc.pool.append(ckpt.PendingTask(extra["pool_idx"][i].copy(),
@@ -915,3 +906,42 @@ class SolverService:
 def _zero_row(arr: np.ndarray, row: int) -> np.ndarray:
     arr[row] = np.zeros_like(arr[row])
     return arr
+
+
+def make_service_round_fns(spec: StackedSpec, backend: str,
+                           steps_per_round: int, *, fused_steps: int = 1,
+                           mesh: Optional[Mesh] = None, max_ship: int = 16):
+    """The service's two jitted closures over ``(lanes, tables)``: the
+    round (expand → instance-scoped steal → per-instance open work) and
+    the CONVERTINDEX stack rebuild run after host-side admission.
+
+    With a mesh both run per device under the same specs — lanes sharded
+    over every mesh axis, stacked tables replicated; the replay is
+    lane-local, so each device rebuilds its own lanes' stacks.
+    """
+    def _rebuild(lanes, tables):
+        return ckpt.rebuild_stacks(spec.bind(tables, backend), lanes)
+
+    if mesh is None:
+        def _round(lanes, tables):
+            return make_round(spec.bind(tables, backend), steps_per_round,
+                              fused_steps=fused_steps)(lanes)
+
+        return jax.jit(_round), jax.jit(_rebuild)
+    axes = tuple(mesh.axis_names)
+
+    def _round(lanes, tables):
+        return make_round(spec.bind(tables, backend), steps_per_round, axes,
+                          max_ship, fused_steps)(lanes)
+
+    proto = spec.bind(StackedTables(*(jnp.asarray(t)
+                                      for t in spec.empty_tables())), backend)
+    lane_specs = lane_partition_specs(proto, axes)
+    table_specs = StackedTables(P(), P(), P())
+    rebuild = jax.jit(jax.shard_map(
+        _rebuild, mesh=mesh, in_specs=(lane_specs, table_specs),
+        out_specs=lane_specs, check_vma=False))
+    round_fn = jax.jit(jax.shard_map(
+        _round, mesh=mesh, in_specs=(lane_specs, table_specs),
+        out_specs=(lane_specs, P()), check_vma=False))
+    return round_fn, rebuild

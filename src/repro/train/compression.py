@@ -38,11 +38,10 @@ def compressed_psum(grads: PyTree, axis_names: Sequence[str],
     grid (required for exact int8 summation; the summed int32 fits easily:
     127 * n_workers << 2^31).
     """
-    from repro.compat import axis_size
     ax = tuple(axis_names)
     n = 1
     for a in ax:
-        n = n * axis_size(a)
+        n = n * jax.lax.axis_size(a)
 
     def one(g, e):
         g = g.astype(jnp.float32) + (e if e is not None else 0.0)

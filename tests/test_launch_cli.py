@@ -84,3 +84,16 @@ def test_solve_cli_rejects_bad_instance_spec(monkeypatch):
     with pytest.raises(SystemExit):
         run_main(["--problem", "ss", "--instance", "reg:10:4:1"],
                  monkeypatch)
+
+
+def test_compile_cache_dir_follows_env(monkeypatch, tmp_path):
+    """The persistent compile cache is placed from outside: the env var
+    wins, otherwise the fixed ``.jax_cache/`` at the checkout root."""
+    import pathlib
+
+    from repro import compile_cache
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.cache_dir() == str(tmp_path)
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.cache_dir() == str(root / ".jax_cache")

@@ -304,7 +304,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import distributed as dist
 from repro.core.engine import Lanes, init_lanes
 from repro.problems import gnp_graph
@@ -332,8 +331,8 @@ def steal_fn(max_ship):
         lambda leaf: P() if f_ in ("best", "steps", "best_payload")
         else P(("workers",)), getattr(proto, f_))
         for f_ in Lanes._fields})
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(specs,),
-                                    out_specs=specs, check=False))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(specs,),
+                                 out_specs=specs, check_vma=False))
 
 
 STEAL = steal_fn(16)

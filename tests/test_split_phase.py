@@ -173,6 +173,37 @@ def test_autotune_choices_are_valid_and_cached():
                 assert best <= cost + 1e-12
 
 
+def test_autotune_reads_peak_table_on_tpu(monkeypatch):
+    """On a TPU the cost model reads the chip's peak-table entry; a
+    device kind missing from the table is an error, not a default."""
+    from repro import peaks
+    v5e = peaks.peaks_for("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.int8_ops, v5e.hbm_bytes_per_s,
+            v5e.hbm_bytes) == (197e12, 393e12, 819e9, 16e9)
+    autotune.clear_cache()
+    try:
+        monkeypatch.setattr(autotune, "_device_kind", lambda: "TPU v5 lite")
+        c = autotune.choose(300, 10, lanes=1024, platform="tpu")
+        assert autotune.predict_cost(300, 10, 1024, 1, tile=c.tile,
+                                     stages=c.stages,
+                                     platform="tpu") is not None
+        monkeypatch.setattr(autotune, "_device_kind", lambda: "TPU v0 x")
+        with pytest.raises(ValueError, match="peak-table"):
+            autotune.choose(300, 10, lanes=1024, platform="tpu")
+    finally:
+        autotune.clear_cache()
+
+
+def test_interpret_mode_is_cpu_only(monkeypatch):
+    assert autotune.resolve_interpret(True) is True
+    assert autotune.resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert autotune.resolve_interpret(None) is True
+    for platform in ("tpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda p=platform: p)
+        assert autotune.resolve_interpret(None) is False
+
+
 def test_tile_validation_errors():
     g = gnp_graph(40, 0.2, seed=1)
     adj = jnp.asarray(g.adj)
@@ -188,6 +219,8 @@ def test_tile_validation_errors():
             bitset_ops.count_stats(adj, m, m, tile=bad)
     with pytest.raises(ValueError, match="stages"):
         bitset_ops.count_stats(adj, m, m, tile=16, stages=3)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        bitset_ops.count_stats(adj, m, m, tile=12, stages=1)
 
 
 # -- 4. fused rounds ----------------------------------------------------------

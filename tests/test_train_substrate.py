@@ -107,10 +107,9 @@ def body(g):
     out, err = compressed_psum({"g": g}, ("data",))
     return out["g"], err["g"]
 
-from repro.compat import shard_map
-fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                       out_specs=(P("data"), P("data")),
-                       check=False))
+fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                           out_specs=(P("data"), P("data")),
+                           check_vma=False))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 mean, err = fn(g)
 true_mean = jnp.mean(g, axis=0)
