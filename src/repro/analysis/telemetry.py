@@ -1,4 +1,4 @@
-"""telemetry-schema: emit()/trace-write call sites checked statically.
+"""telemetry-schema: emit()/trace-write/span call sites checked statically.
 
 PR 7's runtime validation (``solver.emit`` + ``TraceWriter.write`` both
 raise on unknown kinds / missing fields) only fires when the offending
@@ -23,7 +23,11 @@ validator still covers them):
     keeps ordinary file ``.write()`` calls out of scope;
   * ``obj.lifecycle("kind", ...)`` -> kind ∈ TRACE_KINDS (the
     collector renames ``round_no``->``round``, so only membership is
-    checked here).
+    checked here);
+  * ``obs.span("name")`` / ``obs.scope("name")``, and ``span``/``scope``
+    imported from ``repro.obs`` or ``repro.obs.spans`` -> name ∈
+    ``SPAN_NAMES`` / ``SCOPE_NAMES`` (AST-extracted from
+    ``src/repro/obs/spans.py``).
 
 The tables are read from the analyzed module set first (so editing
 ``solver.py`` and linting ``src`` sees the edited table) and fall back
@@ -39,12 +43,36 @@ from repro.analysis.core import Finding, Module, RepoContext, Rule, register
 
 _EVENT_TABLE = ("src/repro/solver.py", "EVENT_KINDS")
 _TRACE_TABLE = ("src/repro/obs/trace.py", "TRACE_KINDS")
+_SPANS_MODULE = "src/repro/obs/spans.py"
+#: profiler helper -> the table of its names in ``_SPANS_MODULE``.
+_NAME_TABLES = {"span": "SPAN_NAMES", "scope": "SCOPE_NAMES"}
+_OBS_MODULES = ("repro.obs", "repro.obs.spans")
 
 
 def _literal_str(node) -> Optional[str]:
     if isinstance(node, ast.Constant) and isinstance(node.value, str):
         return node.value
     return None
+
+
+def _profiler_aliases(tree) -> tuple:
+    """(module aliases of ``repro.obs``/``repro.obs.spans``, bare-name
+    alias -> ``span``/``scope``) bound by the module's imports."""
+    modules, names = set(), {}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                if alias.name in _OBS_MODULES and alias.asname:
+                    modules.add(alias.asname)
+        elif isinstance(node, ast.ImportFrom):
+            for alias in node.names:
+                asname = alias.asname or alias.name
+                if f"{node.module}.{alias.name}" in _OBS_MODULES:
+                    modules.add(asname)
+                elif node.module in _OBS_MODULES and \
+                        alias.name in _NAME_TABLES:
+                    names[asname] = alias.name
+    return modules, names
 
 
 def _expr_mentions_trace(node) -> bool:
@@ -60,7 +88,9 @@ def _expr_mentions_trace(node) -> bool:
 class TelemetrySchemaRule(Rule):
     name = "telemetry-schema"
     description = ("emit()/trace write() call sites must use known "
-                   "EVENT_KINDS/TRACE_KINDS with required fields")
+                   "EVENT_KINDS/TRACE_KINDS with required fields; "
+                   "obs.span()/obs.scope() names known SPAN_NAMES/"
+                   "SCOPE_NAMES")
     severity = "error"
 
     def run(self, ctx: RepoContext) -> List[Finding]:
@@ -71,16 +101,47 @@ class TelemetrySchemaRule(Rule):
         if not isinstance(trace_kinds, dict):
             trace_kinds = None
 
+        tables = {}
+        for helper, table in _NAME_TABLES.items():
+            known = ctx.literal(_SPANS_MODULE, table)
+            if isinstance(known, (set, frozenset)):
+                tables[helper] = (table, known)
+
         findings: List[Finding] = []
         for mod in ctx.modules:
-            if mod.rel in (_EVENT_TABLE[0], _TRACE_TABLE[0]):
+            if mod.rel in (_EVENT_TABLE[0], _TRACE_TABLE[0], _SPANS_MODULE):
                 continue     # the tables' own modules define the schema
+            aliases = _profiler_aliases(mod.tree)
             for call in ast.walk(mod.tree):
                 if not isinstance(call, ast.Call):
                     continue
                 self._check_call(mod, call, event_kinds, trace_kinds,
                                  findings)
+                self._check_name(mod, call, aliases, tables, findings)
         return findings
+
+    def _check_name(self, mod: Module, call: ast.Call, aliases, tables,
+                    findings: List[Finding]) -> None:
+        """``span("name")``/``scope("name")``: the name is in its table."""
+        modules, names = aliases
+        func, helper = call.func, None
+        if isinstance(func, ast.Name):
+            helper = names.get(func.id)
+        elif isinstance(func, ast.Attribute) and \
+                isinstance(func.value, ast.Name) and \
+                func.value.id in modules and func.attr in _NAME_TABLES:
+            helper = func.attr
+        if helper not in tables or not call.args:
+            return
+        name = _literal_str(call.args[0])
+        table, known = tables[helper]
+        if name is not None and name not in known:
+            f = self.finding(mod, call,
+                             f"unknown {helper} name {name!r} — not in "
+                             f"obs.spans.{table} "
+                             f"({', '.join(sorted(known))})")
+            if f:
+                findings.append(f)
 
     def _check_call(self, mod: Module, call: ast.Call, event_kinds,
                     trace_kinds, findings: List[Finding]) -> None:

@@ -24,7 +24,9 @@ The pass works in three stages, all purely static:
    bodies are traced transitively (``round_fn`` → ``expand`` → ``step``
    → ``steal.balance_device`` → ...).  Methods and attribute calls that
    do not resolve to an analyzed function are out of scope (v1
-   limitation, documented in DESIGN.md §10).
+   limitation, documented in DESIGN.md §10).  The profiler's
+   ``repro.obs.spans.span``/``scope`` are host helpers run at trace
+   time by design, and are not propagated into.
 3. **Taint + hazard scan** per traced function: positional parameters
    (minus those with static scalar annotations — ``int``, ``bool``,
    ``Optional[int]`` etc. declare compile-time values) and results of
@@ -72,6 +74,13 @@ _HOST_API = {
 }
 
 _SYNC_METHODS = {"item", "tolist", "block_until_ready"}
+
+#: Host helpers that traced code calls at trace time by design: the
+#: profiler's span and scope (``repro.obs.spans``) check a literal name
+#: against their tables and return a context manager.  Nothing of theirs
+#: is traced, so calls into them do not make them traced functions.
+_TRACE_TIME_HOST = {("src/repro/obs/spans.py", "span"),
+                    ("src/repro/obs/spans.py", "scope")}
 
 
 class _FuncInfo:
@@ -548,7 +557,9 @@ class TraceSafetyRule(Rule):
                 target = project.resolve_func_expr(
                     call.func, info, info.mod)
                 if target is not None:
-                    _mark(target, worklist)
+                    if (target.mod.rel, target.name) not in \
+                            _TRACE_TIME_HOST:
+                        _mark(target, worklist)
                     continue
                 if isinstance(call.func, ast.Name):
                     bound = project.builder_binding(call.func.id, info)

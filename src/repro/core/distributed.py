@@ -45,6 +45,7 @@ from repro.core.api import UNVISITED, BinaryProblem
 from repro.core import steal
 from repro.core.engine import (Lanes, init_lanes, instance_onehot,
                                make_expand)
+from repro.obs.spans import scope, span
 
 
 class SolveStats(NamedTuple):
@@ -153,6 +154,7 @@ def cross_device_steal(problem: BinaryProblem, lanes: Lanes,
 def make_round(problem: BinaryProblem, steps_per_round: int,
                axis_names: Sequence[str] = (), max_ship: int = 16,
                fused_steps: int = 1,
+               on_trace: Optional[Callable[[], None]] = None,
                ) -> Callable[[Lanes], Tuple[Lanes, jnp.ndarray]]:
     """Build the per-device round body (expand → steal → share → count).
 
@@ -160,31 +162,47 @@ def make_round(problem: BinaryProblem, steps_per_round: int,
     tests; otherwise it must run inside shard_map over those axes.
     ``fused_steps`` groups S engine steps per expand-loop iteration
     (tree-identical for any S — see ``make_expand``).
+
+    The body is named ``round_fn``, so its jitted program is
+    ``jit_round_fn`` on one chip and on a mesh.  Its phases run under the
+    ``steal.*`` and ``round.*`` scopes of ``repro.obs.spans``.  The Python
+    body runs only when JAX traces it: there it opens the
+    ``repro.round.trace`` span and calls ``on_trace``, so both count the
+    round's traces.
     """
     expand = make_expand(problem, steps_per_round, fused_steps)
 
     def round_fn(lanes: Lanes) -> Tuple[Lanes, jnp.ndarray]:
-        lanes = expand(lanes)
-        lanes = steal.balance_device(problem, lanes)
-        if axis_names:
-            lanes = cross_device_steal(problem, lanes, axis_names, max_ship)
-            # Paper's notification broadcast: share the incumbent table.
-            best = jax.lax.pmin(lanes.best, tuple(axis_names))
-            lanes = lanes._replace(best=best)
-        # Termination metric PER INSTANCE: active lanes + donatable slots.
-        # The service driver retires instance i when open_work[i] == 0; the
-        # single-instance solve sums the vector.
-        k = lanes.best.shape[0]
-        safe_inst = jnp.clip(lanes.inst, 0, k - 1)
-        slots = steal.donor_slots(lanes)
-        contrib = (lanes.active.astype(jnp.int32)
-                   + (lanes.active
-                      & (slots < lanes.idx.shape[1])).astype(jnp.int32))
-        open_work = jnp.sum(jnp.where(instance_onehot(safe_inst, k),
-                                      contrib[:, None], 0), axis=0)
-        if axis_names:
-            open_work = jax.lax.psum(open_work, tuple(axis_names))
-        return lanes, open_work
+        with span("repro.round.trace"):
+            if on_trace is not None:
+                on_trace()
+            lanes = expand(lanes)
+            with scope("steal.balance_device"):
+                lanes = steal.balance_device(problem, lanes)
+            if axis_names:
+                with scope("steal.cross_device"):
+                    lanes = cross_device_steal(problem, lanes, axis_names,
+                                               max_ship)
+                # Paper's notification broadcast: share the incumbent table.
+                with scope("round.share_best"):
+                    best = jax.lax.pmin(lanes.best, tuple(axis_names))
+                lanes = lanes._replace(best=best)
+            # Termination metric PER INSTANCE: active lanes + donatable
+            # slots.  The service driver retires instance i when
+            # open_work[i] == 0; the single-instance solve sums the vector.
+            with scope("round.open_work"):
+                k = lanes.best.shape[0]
+                safe_inst = jnp.clip(lanes.inst, 0, k - 1)
+                slots = steal.donor_slots(lanes)
+                contrib = (lanes.active.astype(jnp.int32)
+                           + (lanes.active
+                              & (slots < lanes.idx.shape[1])).astype(
+                                  jnp.int32))
+                open_work = jnp.sum(jnp.where(instance_onehot(safe_inst, k),
+                                              contrib[:, None], 0), axis=0)
+                if axis_names:
+                    open_work = jax.lax.psum(open_work, tuple(axis_names))
+            return lanes, open_work
 
     return round_fn
 
@@ -208,11 +226,12 @@ def lane_partition_specs(problem: BinaryProblem,
 
 def make_distributed_round(problem: BinaryProblem, mesh: Mesh,
                            steps_per_round: int, max_ship: int = 16,
-                           fused_steps: int = 1):
+                           fused_steps: int = 1,
+                           on_trace: Optional[Callable[[], None]] = None):
     """shard_map the round over every axis of ``mesh`` (flat worker pool)."""
     axes = tuple(mesh.axis_names)
     round_fn = make_round(problem, steps_per_round, axes, max_ship,
-                          fused_steps)
+                          fused_steps, on_trace)
     in_specs = lane_partition_specs(problem, axes)
     fn = jax.shard_map(round_fn, mesh=mesh, in_specs=(in_specs,),
                        out_specs=(in_specs, P()), check_vma=False)

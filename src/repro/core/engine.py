@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core.api import (DELEGATED, LEFT, RIGHT, UNVISITED, INF_VALUE,
                             BinaryProblem, root_of, tree_select)
+from repro.obs.spans import scope
 
 PyTree = Any
 
@@ -221,11 +222,14 @@ def instance_onehot(inst: jnp.ndarray, k: int) -> jnp.ndarray:
 def make_step(problem: BinaryProblem):
     """Build the vectorized one-step transition Lanes -> Lanes.
 
-    The step is select → evaluate → advance: node states are gathered per
-    lane, evaluated — through ``problem.evaluate_batch`` as ONE batched
-    call when the problem provides it, else ``vmap(evaluate)`` — and the
-    results applied per lane.  Both evaluation paths are bitwise-identical
-    by the ``evaluate_batch`` contract, so the search tree is invariant.
+    The step is select → evaluate → advance → elect: node states are
+    gathered per lane, evaluated — through ``problem.evaluate_batch`` as
+    ONE batched call when the problem provides it, else ``vmap(evaluate)``
+    — the results applied per lane, and each instance's incumbent elected.
+    Both evaluation paths are bitwise-identical by the ``evaluate_batch``
+    contract, so the search tree is invariant.  Each phase runs under its
+    ``engine.*`` scope (``repro.obs.spans``), so its device ops carry the
+    phase's name in the profiler's trace.
     """
 
     select_v = jax.vmap(_select_node)
@@ -238,37 +242,41 @@ def make_step(problem: BinaryProblem):
     def step(lanes: Lanes) -> Lanes:
         w = lanes.active.shape[0]
         k = lanes.best.shape[0]
-        safe_inst = jnp.clip(lanes.inst, 0, k - 1)
-        # Each lane prunes against ITS instance's incumbent.
-        best_per_lane = lanes.best[safe_inst]
-        states, d = select_v(lanes.idx, lanes.depth, lanes.stack)
-        evs = eval_all(states, best_per_lane)
-        (idx, depth, active, stack, visited, improved, vals,
-         payloads) = advance_v(lanes.idx, lanes.depth, lanes.base,
-                               lanes.active, lanes.stack, best_per_lane,
-                               evs, d)
+        with scope("engine.select"):
+            safe_inst = jnp.clip(lanes.inst, 0, k - 1)
+            # Each lane prunes against ITS instance's incumbent.
+            best_per_lane = lanes.best[safe_inst]
+            states, d = select_v(lanes.idx, lanes.depth, lanes.stack)
+        with scope("engine.evaluate"):
+            evs = eval_all(states, best_per_lane)
+        with scope("engine.advance"):
+            (idx, depth, active, stack, visited, improved, vals,
+             payloads) = advance_v(lanes.idx, lanes.depth, lanes.base,
+                                   lanes.active, lanes.stack, best_per_lane,
+                                   evs, d)
+            nodes = lanes.nodes + visited.astype(jnp.int32)
         # Incumbent election per instance (the paper's broadcast, free
         # here): segment-min of the improved values over ``inst``, then the
         # lowest-id winning lane supplies the payload for its instance.
-        mine = instance_onehot(safe_inst, k)                    # [W, K]
-        seg = jnp.min(jnp.where(mine, vals[:, None], INF_VALUE), axis=0)
-        any_improved = seg < lanes.best
-        new_best = jnp.minimum(lanes.best, seg)
-        lane_ids = jnp.arange(w, dtype=jnp.int32)
-        wins = mine & (improved & (vals == seg[safe_inst]))[:, None]
-        winner = jnp.min(jnp.where(wins, lane_ids[:, None], w), axis=0)
-        safe_winner = jnp.clip(winner, 0, w - 1)
+        with scope("engine.elect"):
+            mine = instance_onehot(safe_inst, k)                # [W, K]
+            seg = jnp.min(jnp.where(mine, vals[:, None], INF_VALUE), axis=0)
+            any_improved = seg < lanes.best
+            new_best = jnp.minimum(lanes.best, seg)
+            lane_ids = jnp.arange(w, dtype=jnp.int32)
+            wins = mine & (improved & (vals == seg[safe_inst]))[:, None]
+            winner = jnp.min(jnp.where(wins, lane_ids[:, None], w), axis=0)
+            safe_winner = jnp.clip(winner, 0, w - 1)
 
-        def elect(p, old):
-            upd = any_improved.reshape((k,) + (1,) * (old.ndim - 1))
-            return jnp.where(upd, p[safe_winner], old)
+            def elect(p, old):
+                upd = any_improved.reshape((k,) + (1,) * (old.ndim - 1))
+                return jnp.where(upd, p[safe_winner], old)
 
-        new_payload = jax.tree_util.tree_map(elect, payloads,
-                                             lanes.best_payload)
+            new_payload = jax.tree_util.tree_map(elect, payloads,
+                                                 lanes.best_payload)
         return lanes._replace(
             idx=idx, depth=depth, active=active, stack=stack,
-            best=new_best, best_payload=new_payload,
-            nodes=lanes.nodes + visited.astype(jnp.int32),
+            best=new_best, best_payload=new_payload, nodes=nodes,
             steps=lanes.steps + 1)
 
     return step

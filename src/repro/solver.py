@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro import registry as _registry
 from repro.core.api import BinaryProblem
 from repro.core.distributed import (SolveStats, _gather_lanes, _shard_lanes,
@@ -312,62 +313,70 @@ class Solver:
         ANY lane/device count (elastic restart, paper §VII): surplus tasks
         beyond the new lane count wait in a host-side pool and are
         installed into idle lanes at round boundaries.
+
+        The call's phases are host spans in the profiler's trace
+        (``repro.obs.spans``): ``repro.solve.prepare``, one
+        ``repro.solve.round`` per round holding its
+        ``repro.solve.dispatch`` and ``repro.solve.readback``, and
+        ``repro.solve.finish``.
         """
         from repro.core import checkpoint as ckpt
 
         cfg = self.config
-        problem = self._resolve(problem)
-        mesh = cfg.mesh
-        bootstrap_rounds = cfg.bootstrap_rounds
+        with obs.span("repro.solve.prepare"):
+            problem = self._resolve(problem)
+            mesh = cfg.mesh
+            bootstrap_rounds = cfg.bootstrap_rounds
+            total_lanes = cfg.lanes * (1 if mesh is None
+                                       else int(np.prod(mesh.devices.shape)))
 
-        if mesh is None:
-            round_fn = jax.jit(make_round(problem, cfg.steps_per_round,
-                                          fused_steps=cfg.fused_steps))
-            boot_fn = (jax.jit(make_round(problem, cfg.bootstrap_steps,
-                                          fused_steps=cfg.fused_steps))
-                       if bootstrap_rounds else None)
-            total_lanes = cfg.lanes
-        else:
-            n_dev = int(np.prod(mesh.devices.shape))
-            round_fn = make_distributed_round(
-                problem, mesh, cfg.steps_per_round, cfg.max_ship,
-                fused_steps=cfg.fused_steps)
-            boot_fn = (make_distributed_round(
-                problem, mesh, cfg.bootstrap_steps, cfg.max_ship,
-                fused_steps=cfg.fused_steps)
-                if bootstrap_rounds else None)
-            total_lanes = cfg.lanes * n_dev
+            pool: list = []
+            if cfg.resume_from is not None:
+                if not os.path.exists(cfg.resume_from):
+                    raise ConfigError(
+                        f"resume_from checkpoint not found: {cfg.resume_from}")
+                try:
+                    lanes, pool = ckpt.restore(cfg.resume_from, problem,
+                                               total_lanes)
+                except ValueError as e:    # e.g. instance-slot mismatch
+                    raise ConfigError(
+                        f"resume_from {cfg.resume_from!r} is incompatible "
+                        f"with this problem/config: {e}") from e
+                bootstrap_rounds = max(bootstrap_rounds, 1)  # respread work
+            else:
+                lanes = init_lanes(problem, total_lanes)
+            if mesh is not None:
+                lanes = _shard_lanes(lanes, mesh)
 
-        pool: list = []
-        if cfg.resume_from is not None:
-            if not os.path.exists(cfg.resume_from):
-                raise ConfigError(
-                    f"resume_from checkpoint not found: {cfg.resume_from}")
-            try:
-                lanes, pool = ckpt.restore(cfg.resume_from, problem,
-                                           total_lanes)
-            except ValueError as e:        # e.g. instance-slot mismatch
-                raise ConfigError(
-                    f"resume_from {cfg.resume_from!r} is incompatible with "
-                    f"this problem/config: {e}") from e
-            bootstrap_rounds = max(bootstrap_rounds, 1)  # respread work
-        else:
-            lanes = init_lanes(problem, total_lanes)
-        if mesh is not None:
-            lanes = _shard_lanes(lanes, mesh)
+            collector = on_trace = None
+            if cfg.metrics or cfg.trace_path is not None:
+                collector = obs.RoundCollector(
+                    mode="solve", lanes=total_lanes,
+                    slots=problem.num_instances,
+                    steps_per_round=cfg.steps_per_round,
+                    fused_steps=cfg.fused_steps, backend=cfg.backend,
+                    trace=(obs.TraceWriter(cfg.trace_path)
+                           if cfg.trace_path else None))
+                collector.start(lanes)  # after restore: deltas = this run
+                on_trace = collector.registry.counter(
+                    "round_traces",
+                    "times the round's Python body was traced").inc
+            self._obs = collector
 
-        collector = None
-        if cfg.metrics or cfg.trace_path is not None:
-            from repro import obs
-            collector = obs.RoundCollector(
-                mode="solve", lanes=total_lanes,
-                slots=problem.num_instances,
-                steps_per_round=cfg.steps_per_round,
-                fused_steps=cfg.fused_steps, backend=cfg.backend,
-                trace=(obs.TraceWriter(cfg.trace_path)
-                       if cfg.trace_path else None))
-            collector.start(lanes)      # after restore: deltas = this run
-        self._obs = collector
+            # jit traces each new round when it is first called, in the
+            # first dispatch; ``on_trace`` counts those traces.
+            if mesh is None:
+                def build(steps):
+                    return jax.jit(make_round(problem, steps,
+                                              fused_steps=cfg.fused_steps,
+                                              on_trace=on_trace))
+            else:
+                def build(steps):
+                    return make_distributed_round(
+                        problem, mesh, steps, cfg.max_ship,
+                        fused_steps=cfg.fused_steps, on_trace=on_trace)
+            round_fn = build(cfg.steps_per_round)
+            boot_fn = build(cfg.bootstrap_steps) if bootstrap_rounds else None
 
         def feed_pool(lanes):
             nonlocal pool
@@ -382,61 +391,66 @@ class Solver:
             return (collector.snapshot()
                     if collector is not None and cfg.metrics else None)
 
-        rounds, done = 0, False
-        for _ in range(bootstrap_rounds):
+        def run_round(fn, lanes, round_no):
+            """Round ``round_no``: feed, dispatch, read back, collect."""
             fed = bool(pool)
             lanes = feed_pool(lanes)
             if collector is not None:
                 collector.before_round(lanes, dirty=fed)
-            lanes, open_work = boot_fn(lanes) if boot_fn else round_fn(lanes)
-            rounds += 1
-            open_now = int(jnp.sum(open_work))
+            with obs.span("repro.solve.dispatch"):
+                lanes, open_work = fn(lanes)
+            with obs.span("repro.solve.readback"):
+                open_now = int(jnp.sum(open_work))
             if collector is not None:
-                collector.after_round(rounds, lanes, open_now)
+                collector.after_round(round_no, lanes, open_now)
+            return lanes, open_now
+
+        rounds, done = 0, False
+        for _ in range(bootstrap_rounds):
+            rounds += 1
+            with obs.span("repro.solve.round"):
+                lanes, open_now = run_round(boot_fn or round_fn, lanes,
+                                            rounds)
             if open_now == 0 and not pool:
                 done = True
                 break
         while not done and rounds < cfg.max_rounds:
-            fed = bool(pool)
-            lanes = feed_pool(lanes)
-            if collector is not None:
-                collector.before_round(lanes, dirty=fed)
-            lanes, open_work = round_fn(lanes)
             rounds += 1
-            open_now = int(jnp.sum(open_work))
-            if collector is not None:
-                collector.after_round(rounds, lanes, open_now)
-            if self.on_event is not None:
-                # The incumbent readback costs a device sync — only pay it
-                # when someone is listening.
-                emit(self.on_event, "round", round=rounds,
-                     open_work=open_now, best=int(jnp.min(lanes.best)),
-                     lanes=lanes, metrics=snap())
-            if (cfg.checkpoint_every and cfg.checkpoint_path
-                    and rounds % cfg.checkpoint_every == 0):
-                ckpt.save(cfg.checkpoint_path, _gather_lanes(lanes))
-                emit(self.on_event, "checkpoint", round=rounds,
-                     path=cfg.checkpoint_path)
+            with obs.span("repro.solve.round"):
+                lanes, open_now = run_round(round_fn, lanes, rounds)
+                if self.on_event is not None:
+                    # The incumbent readback costs a device sync — only pay
+                    # it when someone is listening.
+                    emit(self.on_event, "round", round=rounds,
+                         open_work=open_now, best=int(jnp.min(lanes.best)),
+                         lanes=lanes, metrics=snap())
+                if (cfg.checkpoint_every and cfg.checkpoint_path
+                        and rounds % cfg.checkpoint_every == 0):
+                    ckpt.save(cfg.checkpoint_path, _gather_lanes(lanes))
+                    emit(self.on_event, "checkpoint", round=rounds,
+                         path=cfg.checkpoint_path)
             if open_now == 0 and not pool:
                 done = True
 
-        stats = SolveStats(
-            best=int(jnp.min(lanes.best)),
-            rounds=rounds,
-            nodes=int(jnp.sum(lanes.nodes)),
-            t_s=int(jnp.sum(lanes.t_s)),
-            t_r=int(jnp.sum(lanes.t_r)),
-            donated=int(jnp.sum(lanes.donated)),
-            lanes=int(lanes.active.shape[0]),
-            t_c=int(jnp.sum(lanes.t_c)),
-        )
-        if collector is not None:
-            collector.finish(rounds=rounds,
-                             best=[int(b) for b in np.asarray(lanes.best)])
-            collector.close()
-        emit(self.on_event, "done", round=rounds, open_work=0,
-             best=stats.best, metrics=snap())
-        best_payload = jax.tree_util.tree_map(np.asarray, lanes.best_payload)
+        with obs.span("repro.solve.finish"):
+            stats = SolveStats(
+                best=int(jnp.min(lanes.best)),
+                rounds=rounds,
+                nodes=int(jnp.sum(lanes.nodes)),
+                t_s=int(jnp.sum(lanes.t_s)),
+                t_r=int(jnp.sum(lanes.t_r)),
+                donated=int(jnp.sum(lanes.donated)),
+                lanes=int(lanes.active.shape[0]),
+                t_c=int(jnp.sum(lanes.t_c)),
+            )
+            if collector is not None:
+                collector.finish(rounds=rounds,
+                                 best=[int(b) for b in np.asarray(lanes.best)])
+                collector.close()
+            emit(self.on_event, "done", round=rounds, open_work=0,
+                 best=stats.best, metrics=snap())
+            best_payload = jax.tree_util.tree_map(np.asarray,
+                                                  lanes.best_payload)
         if problem.num_instances == 1:
             # Single-instance API: drop the K=1 incumbent-table dim.
             best_payload = jax.tree_util.tree_map(lambda p: p[0],

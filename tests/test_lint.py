@@ -79,6 +79,20 @@ def test_telemetry_schema_finds_every_shape():
     assert len(result.findings) == 5
 
 
+def test_telemetry_schema_checks_span_and_scope_names():
+    bad = _lint(f"{FIXTURES}/spans_bad.py")
+    assert _rules_hit(bad) == {"telemetry-schema"}, bad.findings
+    messages = " | ".join(f.message for f in bad.findings)
+    assert "unknown span name 'repro.solve.warp'" in messages
+    assert "unknown span name 'solve.round'" in messages
+    assert "unknown scope name 'repro.solve.round'" in messages
+    assert "unknown scope name 'engine.expand'" in messages
+    assert "unknown scope name 'steal.global'" in messages
+    assert len(bad.findings) == 5
+    good = _lint(f"{FIXTURES}/spans_good.py")
+    assert good.findings == [], [f.format() for f in good.findings]
+
+
 def test_api_hygiene_deprecation_clauses():
     result = _lint(f"{FIXTURES}/api_hygiene_bad.py")
     messages = " | ".join(f.message for f in result.findings)
