@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.api import UNVISITED, INF_VALUE, BinaryProblem
-from repro.core.engine import Lanes, init_lanes, replay_path
+from repro.core.engine import Lanes, init_lanes, replay_lanes
 
 _EXTRA_PREFIX = "extra_"
 
@@ -253,19 +253,14 @@ def rebuild_stacks(problem: BinaryProblem, lanes: Lanes) -> Lanes:
     The path to a lane's *current node* is ``idx[0..depth-1]`` with
     delegation marks flattened to the branch actually taken (DELEGATED means
     the donor went left).  Replay starts from the root of the lane's OWN
-    instance.  O(W · D_MAX) applies — paid once per restore.
+    instance, in one batched replay over the lane block
+    (``engine.replay_lanes``) as deep as the deepest active lane: at most
+    D_MAX applies over all lanes, paid once per restore or admission.
     """
-    bits = jnp.where(lanes.idx < 0, jnp.int8(0), lanes.idx)
     k = lanes.best.shape[0]
     safe_inst = jnp.clip(lanes.inst, 0, k - 1)
-    stacks = jax.vmap(
-        lambda b, d, s, i: replay_path(problem, b, d, s, i)
-    )(bits, lanes.depth, lanes.stack, safe_inst)
-    keep = lanes.active
-    stack = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(
-            keep.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
-        stacks, lanes.stack)
+    stack = replay_lanes(problem, lanes.idx, lanes.depth, safe_inst,
+                         lanes.active, lanes.stack)
     return lanes._replace(stack=stack)
 
 

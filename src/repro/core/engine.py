@@ -337,39 +337,55 @@ def make_expand(problem: BinaryProblem, num_steps: int,
     return expand
 
 
-def replay_path(problem: BinaryProblem, bits: jnp.ndarray,
-                path_depth: jnp.ndarray, stack: PyTree,
-                inst: jnp.ndarray = jnp.int32(0)) -> PyTree:
-    """CONVERTINDEX: rebuild the state stack for a task index (paper §IV-A).
+def replay_lanes(problem: BinaryProblem, bits: jnp.ndarray,
+                 depth: jnp.ndarray, inst: jnp.ndarray, recv: jnp.ndarray,
+                 stack: PyTree) -> PyTree:
+    """CONVERTINDEX for a block of lanes: rebuild the receivers' stacks
+    from their task indices (paper §IV-A).
 
-    Starting from the root of instance ``inst`` (plain ``root()`` for
-    single-instance problems), re-applies the branch decisions ``bits[0..
-    path_depth-1]`` (delegation marks already flattened to LEFT by
-    FIXINDEX).  Fills ``stack[j]`` for j = 0..path_depth and returns the new
-    stack.  The cost is O(D_MAX) child derivations (``Problem.apply``, i.e.
-    ``evaluate`` with the non-child outputs dead-code-eliminated) — the
-    paper's serial-overhead term, incurred once per received task.
+    ``bits`` int8[W, IL] holds each lane's path (delegation marks already
+    flattened to LEFT by FIXINDEX; a bit is read as ``clip(bit, 0, 1)``),
+    ``depth``/``inst`` int32[W] its task depth and instance, and ``recv``
+    bool[W] which lanes receive.  For a receiving lane, slot 0 becomes the
+    root of its instance and slot ``j + 1`` the state after ``bits[0..j]``
+    for ``j < depth``; the slots deeper than ``depth``, and every slot of
+    the other lanes, are returned untouched.
+
+    One loop carries the current state of every lane and writes each new
+    state into a depth-major buffer (leaves ``[STACK_LEN, W, ...]``); it
+    never reads the lane-major stack, so the stack is not relaid out on
+    each trip.  The loop runs only as deep as the deepest received task
+    (zero trips when nobody receives), each trip one ``Problem.apply`` over
+    all lanes.  One select then merges the buffer into the stack.
     """
-    il = bits.shape[0]
-    root = root_of(problem, inst)
-    stack = jax.tree_util.tree_map(
-        lambda s, r: jax.lax.dynamic_update_index_in_dim(s, r, 0, axis=0),
-        stack, root)
+    il = bits.shape[1]
+    apply_all = jax.vmap(problem.apply)
+    root = jax.vmap(lambda i: root_of(problem, i))(inst)
+    bits_t = jnp.clip(bits.astype(jnp.int32), 0, 1).T          # [IL, W]
+    trips = jnp.minimum(jnp.max(jnp.where(recv, depth, 0)), il)
 
-    def body(j, carry):
-        state, stack = carry
-        bit = jnp.clip(bits[j].astype(jnp.int32), 0, 1)
-        nxt = problem.apply(state, bit)
-        take = j < path_depth
-        state = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(take, b, a), state, nxt)
-        stack = jax.tree_util.tree_map(
-            lambda s, st: jax.lax.dynamic_update_index_in_dim(
-                s, jnp.where(take, st,
-                             jax.lax.dynamic_index_in_dim(s, jnp.clip(j + 1, 0, s.shape[0] - 1), keepdims=False)),
-                jnp.clip(j + 1, 0, s.shape[0] - 1), axis=0),
-            stack, state)
-        return state, stack
+    buf = jax.tree_util.tree_map(
+        lambda r: jnp.zeros((il + 1,) + r.shape, r.dtype).at[0].set(r), root)
 
-    _, stack = jax.lax.fori_loop(0, il, body, (root, stack))
-    return stack
+    def cond(carry):
+        return carry[0] < trips
+
+    def body(carry):
+        j, state, buf = carry
+        state = apply_all(state, jax.lax.dynamic_index_in_dim(
+            bits_t, j, keepdims=False))
+        buf = jax.tree_util.tree_map(
+            lambda b, st: jax.lax.dynamic_update_index_in_dim(
+                b, st, j + 1, axis=0), buf, state)
+        return j + 1, state, buf
+
+    _, _, buf = jax.lax.while_loop(cond, body, (jnp.int32(0), root, buf))
+
+    slot = jnp.arange(il + 1, dtype=jnp.int32)
+    take = recv[:, None] & (slot[None, :] <= depth[:, None])    # [W, SL]
+
+    def merge(b, old):
+        t = take.reshape(take.shape + (1,) * (old.ndim - 2))
+        return jnp.where(t, jnp.swapaxes(b, 0, 1), old)
+
+    return jax.tree_util.tree_map(merge, buf, stack)

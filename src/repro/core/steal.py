@@ -24,14 +24,13 @@ ranked matching.
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.api import UNVISITED, BinaryProblem
-from repro.core.engine import Lanes, replay_path
+from repro.core.engine import Lanes, replay_lanes
 from repro.core.indexing import extract_task, heaviest_open_slot
 
 
@@ -162,22 +161,16 @@ def install_tasks(problem: BinaryProblem, lanes: Lanes, bits: jnp.ndarray,
 
     Row ``i`` goes to lane ``i`` — callers route tasks to specific thief
     lanes (``valid`` gates installation; it must only be set on idle
-    lanes).  Receiving lanes replay the index through ``Problem.apply``
-    (CONVERTINDEX) from the root of the task's instance to rebuild their
-    state stack, then resume as owners of the stolen subtree (base = task
-    depth).  ``cross`` (a static flag, True from ``cross_device_steal``)
-    additionally bumps the receiver's ``t_c`` counter so telemetry can
-    split steal traffic into intra- vs cross-device scope.
+    lanes).  Receiving lanes rebuild their state stacks from the root of
+    the task's instance in one batched CONVERTINDEX replay
+    (``engine.replay_lanes``, as deep as the deepest received task), then
+    resume as owners of the stolen subtree (base = task depth).  ``cross``
+    (a static flag, True from ``cross_device_steal``) additionally bumps
+    the receiver's ``t_c`` counter so telemetry can split steal traffic
+    into intra- vs cross-device scope.
     """
     my_valid = valid & ~lanes.active
-
-    # CONVERTINDEX replay for receiving lanes (vectorized, masked).
-    replay = jax.vmap(functools.partial(replay_path, problem))
-    new_stack = replay(bits, tdepth, lanes.stack, tinst)
-    stack = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(
-            my_valid.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
-        new_stack, lanes.stack)
+    stack = replay_lanes(problem, bits, tdepth, tinst, my_valid, lanes.stack)
 
     idx = jnp.where(my_valid[:, None], bits, lanes.idx)
     recv = my_valid.astype(jnp.int32)

@@ -27,8 +27,10 @@ search tree is bit-identical with telemetry on or off.
 Shipped-subtree depth: a lane whose ``t_s`` rose this round received a
 stolen task, and ``base`` is exactly the installed task's depth — so the
 ship-size histogram (subtree depth ≈ log-size proxy) costs nothing
-extra.  Kernel dispatches are ``ceil(steps / fused_steps)`` per round —
-the expand loop launches one fused group per iteration (DESIGN.md §5.5).
+extra, and so does the replay's trip count: the batched replay runs as
+deep as the deepest task received.  Kernel dispatches are
+``ceil(steps / fused_steps)`` per round — the expand loop launches one
+fused group per iteration (DESIGN.md §5.5).
 """
 
 from __future__ import annotations
@@ -92,6 +94,9 @@ class RoundCollector:
         self.h_ship = r.histogram("steal_ship_depth",
                                   "depth of shipped subtree roots",
                                   buckets=_SHIP_BUCKETS)
+        self.c_replay = r.counter(
+            "steal_replay_steps",
+            "CONVERTINDEX replay trips: the deepest shipped task a round")
         self.g_dev_nodes = r.gauge(
             "device_nodes", "nodes expanded last round, per device shard")
         self.g_dev_active = r.gauge(
@@ -191,6 +196,7 @@ class RoundCollector:
         self.g_open.set(int(open_total))
         for depth in ship_depths:
             self.h_ship.observe(depth)
+        self.c_replay.inc(max(ship_depths, default=0))
         if self.mode == "service":
             self.g_queue.set(int(queue_depth))
 

@@ -229,6 +229,20 @@ def test_solve_trace_report_cross_checks(tmp_path):
     assert "load balance" in trace_report.render(report)
 
 
+def test_replay_steps_count_the_deepest_task_a_round(tmp_path):
+    """``steal_replay_steps`` adds, round by round, the depth of the
+    deepest task received: the batched replay's trip count."""
+    trace = str(tmp_path / "solve.jsonl")
+    solver = Solver(SolverConfig(lanes=8, steps_per_round=8,
+                                 bootstrap_rounds=2, bootstrap_steps=4,
+                                 metrics=True, trace_path=trace))
+    solver.solve(VC)
+    rounds = [r for r in read_trace(trace) if r["t"] == "round"]
+    trips = [max(r["ship_depths"], default=0) for r in rounds]
+    assert any(trips) and not all(trips)
+    assert solver.metrics().value("steal_replay_steps") == sum(trips)
+
+
 @pytest.mark.slow
 def test_service_trace_report_k8_drain(tmp_path):
     """K=8 drain through the service with telemetry: the per-instance node

@@ -14,6 +14,8 @@ compilation cache is off around the compiles, since an entry written for
 a described chip cannot be read back without one.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -202,3 +204,25 @@ def test_mesh_service_round_and_rebuild_compile(mesh4, on_tpu):
     for fn in (round_fn, rebuild):
         assert KERNEL_MARK in fn.lower(lanes, tables).compile().as_text()
 
+
+
+def test_vc_round_replay_keeps_the_stack_in_place(one_chip):
+    """The benchmark's round (vc, G(125, 0.10), 768 lanes, 64 steps): the
+    steal's replay must not relayout the lane stack.  No copy makes a
+    ``[768, 127, 4]`` stack leaf anywhere, and no loop body copies any
+    ``[768, 127, ...]`` array; the copies that are left run once a round."""
+    prob = make_vertex_cover(registry.get("vc").parse("gnp:125:10:1"))
+    lanes = jax.eval_shape(lambda: init_lanes(prob, 768))
+    text = _compile(make_round(prob, 64), _shapes(lanes, one_chip)).as_text()
+    entry, comp, looped = None, None, []
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(2)
+            entry = comp if head.group(1) else entry
+        copy = re.search(r"%copy[.\d]* = \w+\[768,127(,\d+)*\]", line)
+        if copy:
+            assert "[768,127,4]" not in copy.group(0), line
+            looped.append(comp)
+    assert entry is not None
+    assert all(c == entry for c in looped), looped
