@@ -20,7 +20,8 @@ Under ``jnp`` and ``pallas`` every phase must give the identical optimum,
 node count, T_S and T_R, the optimum must equal the oracle's, and the
 compiled ``pallas`` round must contain the Mosaic kernel
 (``tpu_custom_call``).  ``--chips 4`` runs (b) on a 4-device mesh against
-the one-chip solve and the oracle, and the sharded service on (d)'s
+the one-chip solve and the oracle, checks that the mesh's cover is a cover
+of the graph of the optimum's size, and runs the sharded service on (d)'s
 requests against the one-chip service, and prints how many lane rows each
 device holds.
 
@@ -209,6 +210,19 @@ def service_phase(env, clock, label, mix, *, lanes, mesh=None,
     return got
 
 
+def cover_check(graph, words) -> tuple:
+    """(vertices in the packed set ``words``, edges of ``graph`` it leaves
+    uncovered)."""
+    import numpy as np
+
+    words = np.asarray(words, np.uint32).reshape(-1)
+    v = np.arange(graph.n)
+    chosen = ((words[v // 32] >> (v % 32).astype(np.uint32)) & 1) == 1
+    adj = ((graph.adj[:, v // 32] >> (v % 32).astype(np.uint32)) & 1) == 1
+    return (int(np.bitwise_count(words).sum()),
+            int(np.triu(adj & ~chosen[:, None] & ~chosen[None, :]).sum()))
+
+
 def lane_rows(arr) -> list:
     """(device id, lane rows) for each shard of a lane-sharded array."""
     return sorted((s.device.id, int(s.data.shape[0]))
@@ -256,6 +270,13 @@ def four_chips(env, clock, devices) -> None:
           f"4b: lanes not spread over 4 devices: {rows}")
     check(one["pallas"][0]["optimum"] == four["pallas"][0]["optimum"],
           "4b: mesh optimum differs from the one-chip optimum")
+    graph = env.registry.get(VC_SEARCH[0]).parse(VC_SEARCH[1])
+    size, uncovered = cover_check(graph, four["pallas"][1].payload)
+    print(f"  4b mesh cover: {size} vertices, {uncovered} edges uncovered",
+          flush=True)
+    check(uncovered == 0 and size == want_b,
+          f"4b: mesh payload is not a cover of {want_b} vertices "
+          f"({size} vertices, {uncovered} edges uncovered)")
 
     print("phase (4d): sharded service vs one-chip service", flush=True)
     want_d = clock.run("4d oracle", lambda: {

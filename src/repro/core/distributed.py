@@ -7,7 +7,8 @@ bulk-synchronous rounds on a TPU mesh (DESIGN.md §2):
   round := expand(R engine steps)            # pure lane-local compute
            → intra-device steal              # lanes balance within a chip
            → cross-device steal              # collectives over the mesh
-           → incumbent all-reduce(min)       # paper's notification broadcast
+           → incumbent all-reduce(min)       # paper's notification broadcast:
+                                             # the value, then its solution
            → termination all-reduce          # paper's 3-state protocol
 
 Cross-device steal (deterministic, loss-free):
@@ -22,7 +23,8 @@ Cross-device steal (deterministic, loss-free):
      makes this affordable at 512+ devices;
   4. device r's idle lanes claim the tasks whose global rank matches their
      global thief rank (pure arithmetic, no extra messages);
-  5. psum-min of the incumbent; the round loop ends when the global number
+  5. pmin of the incumbent, then its solution from the device that holds
+     it (``share_best``); the round loop ends when the global number
      of active lanes and donatable tasks are both zero.
 
 The host driver (``repro.solver.Solver.solve``) runs these jitted rounds in
@@ -151,6 +153,32 @@ def cross_device_steal(problem: BinaryProblem, lanes: Lanes,
                                cross=True)
 
 
+def share_best(lanes: Lanes, axis_names: Sequence[str]) -> Lanes:
+    """Elect each instance's incumbent, value and solution, across devices.
+
+    The value is the ``pmin`` of ``best``; the solution is the one held by
+    the lowest-ranked device whose own ``best`` equals it, broadcast by a
+    ``psum`` in which every other device adds zeros.  Afterwards every
+    device holds the same ``(best, best_payload)``, so the replicated
+    incumbent table is replicated in fact and its payload is a solution of
+    value ``best``.
+    """
+    ax = tuple(axis_names)
+    k = lanes.best.shape[0]
+    best = jax.lax.pmin(lanes.best, ax)
+    me = _axis_rank(ax)
+    nobody = jnp.int32(np.iinfo(np.int32).max)
+    owner = jax.lax.pmin(jnp.where(lanes.best == best, me, nobody), ax)
+    mine = owner == me                                          # [K]
+
+    def elect(p):
+        keep = mine.reshape((k,) + (1,) * (p.ndim - 1))
+        return jax.lax.psum(jnp.where(keep, p, jnp.zeros_like(p)), ax)
+
+    return lanes._replace(best=best, best_payload=jax.tree_util.tree_map(
+        elect, lanes.best_payload))
+
+
 def make_round(problem: BinaryProblem, steps_per_round: int,
                axis_names: Sequence[str] = (), max_ship: int = 16,
                fused_steps: int = 1,
@@ -185,8 +213,7 @@ def make_round(problem: BinaryProblem, steps_per_round: int,
                                                max_ship)
                 # Paper's notification broadcast: share the incumbent table.
                 with scope("round.share_best"):
-                    best = jax.lax.pmin(lanes.best, tuple(axis_names))
-                lanes = lanes._replace(best=best)
+                    lanes = share_best(lanes, axis_names)
             # Termination metric PER INSTANCE: active lanes + donatable
             # slots.  The service driver retires instance i when
             # open_work[i] == 0; the single-instance solve sums the vector.

@@ -189,6 +189,24 @@ def test_mesh_solve_round_compiles(mesh4, on_tpu):
     assert KERNEL_MARK in text and "all-gather" in text
 
 
+def test_cell_mesh_round_elects_the_cover_across_chips(mesh4):
+    """The four-chip benchmark round (vc, G(150, 0.10), 768 lanes a chip,
+    64 steps, the default backend) compiles for the described host, and
+    the incumbent's cover (``u32[1, 5]``) is all-reduced under the
+    ``round.share_best`` scope."""
+    prob = make_vertex_cover(registry.get("vc").parse("gnp:150:10:1"))
+    specs = lane_partition_specs(prob, mesh4.axis_names)
+    lanes = jax.eval_shape(lambda: init_lanes(prob, 4 * 768))
+    args = jax.tree_util.tree_map(
+        lambda l, s: _spec(l.shape, l.dtype, NamedSharding(mesh4, s)),
+        lanes, specs)
+    text = make_distributed_round(prob, mesh4, 64).lower(
+        args).compile().as_text()
+    assert [line for line in text.splitlines()
+            if re.search(r"= u32\[1,5\]\S* all-reduce(-start)?\(", line)
+            and "round.share_best" in line]
+
+
 def test_mesh_service_round_and_rebuild_compile(mesh4, on_tpu):
     spec = StackedSpec(n=N, k=SLOTS)
     round_fn, rebuild = make_service_round_fns(spec, "pallas", STEPS,
