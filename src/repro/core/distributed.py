@@ -27,11 +27,16 @@ Cross-device steal (deterministic, loss-free):
      it (``share_best``); the round loop ends when the global number
      of active lanes and donatable tasks are both zero.
 
-The host driver (``repro.solver.Solver.solve``) runs these jitted rounds in
-a Python loop so that checkpointing (paper §VII: persist ``current_idx``),
-elastic re-sharding and fault injection happen at round boundaries — the
-production posture for restartable long jobs.  The kwarg-style ``solve``
-kept here is a deprecated shim over that facade (DESIGN.md §6).
+The host driver (``repro.solver.Solver.solve``) runs these rounds in a
+device loop (:func:`jit_round_loop`): one dispatch runs rounds until no
+work is open or its round budget is spent.  The host is consulted between
+rounds only when it needs the round: feeding the pending pool of an
+elastic restart, an ``on_event`` listener or the telemetry collector (one
+round a dispatch while any is there), and checkpointing (paper §VII:
+persist ``current_idx``; a dispatch runs to the next checkpoint
+boundary).  Otherwise a dispatch holds every round left.  The
+kwarg-style ``solve`` kept here is a deprecated shim over that facade
+(DESIGN.md §6).
 """
 
 from __future__ import annotations
@@ -232,6 +237,42 @@ def make_round(problem: BinaryProblem, steps_per_round: int,
             return lanes, open_work
 
     return round_fn
+
+
+def jit_round_loop(body: Callable[[Lanes], Tuple[Lanes, jnp.ndarray]],
+                   problem: BinaryProblem, mesh: Optional[Mesh] = None):
+    """jit a device loop around the one-round ``body`` (``lanes ->
+    (lanes, open_work)``, as :func:`make_round` builds it).
+
+    The loop takes ``(lanes, budget)`` and returns ``(lanes, open_work,
+    rounds_run)``.  Its first round always runs; later ones run while any
+    instance has open work and fewer than ``budget`` rounds have run.
+    ``budget`` is a traced int32, so every budget is the same program.
+    With a ``mesh`` the loop runs inside ``shard_map`` over every mesh
+    axis (``body`` is then a round built for those axes): ``open_work`` is
+    already summed over the mesh, so every device runs the same rounds.
+    The loop is named ``round_fn``, so its program is ``jit_round_fn``.
+    """
+    def round_fn(lanes: Lanes, budget: jnp.ndarray,
+                 ) -> Tuple[Lanes, jnp.ndarray, jnp.ndarray]:
+        def more(carry):
+            _, open_work, ran = carry
+            return (ran == 0) | ((jnp.sum(open_work) > 0) & (ran < budget))
+
+        def one(carry):
+            lanes, _, ran = carry
+            lanes, open_work = body(lanes)
+            return lanes, open_work, ran + 1
+
+        none_yet = jnp.zeros(lanes.best.shape, jnp.int32)
+        return jax.lax.while_loop(more, one, (lanes, none_yet, jnp.int32(0)))
+
+    if mesh is None:
+        return jax.jit(round_fn)
+    specs = lane_partition_specs(problem, mesh.axis_names)
+    return jax.jit(jax.shard_map(round_fn, mesh=mesh, in_specs=(specs, P()),
+                                 out_specs=(specs, P(), P()),
+                                 check_vma=False))
 
 
 def lane_partition_specs(problem: BinaryProblem,

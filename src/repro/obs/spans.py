@@ -28,9 +28,9 @@ __all__ = ["SCOPE_NAMES", "SPAN_NAMES", "scope", "span"]
 #: Host spans of the solve driver, and the trace-time span of the round.
 SPAN_NAMES = frozenset({
     "repro.solve.prepare",    # resolve, build and jit the round, lanes
-    "repro.solve.round",      # one round: feed, dispatch, readback, hooks
-    "repro.solve.dispatch",   # the call of the jitted round
-    "repro.solve.readback",   # the open-work sync that ends the round
+    "repro.solve.round",      # one dispatch: feed, dispatch, readback, hooks
+    "repro.solve.dispatch",   # the call of the jitted round loop
+    "repro.solve.readback",   # the open-work sync that ends the dispatch
     "repro.solve.finish",     # the stats and payload readbacks
     "repro.round.trace",      # the round's Python body, run when traced
 })

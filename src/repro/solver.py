@@ -44,7 +44,7 @@ from repro import obs
 from repro import registry as _registry
 from repro.core.api import BinaryProblem
 from repro.core.distributed import (SolveStats, _gather_lanes, _shard_lanes,
-                                    make_distributed_round, make_round)
+                                    jit_round_loop, make_round)
 from repro.core.engine import Lanes, init_lanes
 from repro.core.serial import serial_rb
 
@@ -314,9 +314,21 @@ class Solver:
         beyond the new lane count wait in a host-side pool and are
         installed into idle lanes at round boundaries.
 
+        Rounds run in a device loop (``distributed.jit_round_loop``):
+        one dispatch runs rounds until no work is open or its budget is
+        spent, and one readback then fetches the open work and the rounds
+        it ran.  The host needs a round back only to feed the resume pool,
+        to hand it to ``on_event`` or the telemetry collector
+        (``metrics``, ``trace_path``), or to checkpoint it.  So a dispatch
+        runs one round while any of the first three is there, as far as
+        the next checkpoint boundary with ``checkpoint_every``, and
+        otherwise every round left to ``max_rounds``: a plain solve is one
+        dispatch, plus one for its bootstrap rounds.  The rounds, and so
+        the stats and the payload, are the same for every budget.
+
         The call's phases are host spans in the profiler's trace
         (``repro.obs.spans``): ``repro.solve.prepare``, one
-        ``repro.solve.round`` per round holding its
+        ``repro.solve.round`` per dispatch holding its
         ``repro.solve.dispatch`` and ``repro.solve.readback``, and
         ``repro.solve.finish``.
         """
@@ -365,16 +377,14 @@ class Solver:
 
             # jit traces each new round when it is first called, in the
             # first dispatch; ``on_trace`` counts those traces.
-            if mesh is None:
-                def build(steps):
-                    return jax.jit(make_round(problem, steps,
-                                              fused_steps=cfg.fused_steps,
-                                              on_trace=on_trace))
-            else:
-                def build(steps):
-                    return make_distributed_round(
-                        problem, mesh, steps, cfg.max_ship,
-                        fused_steps=cfg.fused_steps, on_trace=on_trace)
+            axes = () if mesh is None else tuple(mesh.axis_names)
+
+            def build(steps):
+                return jit_round_loop(
+                    make_round(problem, steps, axes, cfg.max_ship,
+                               fused_steps=cfg.fused_steps,
+                               on_trace=on_trace),
+                    problem, mesh)
             round_fn = build(cfg.steps_per_round)
             boot_fn = build(cfg.bootstrap_steps) if bootstrap_rounds else None
 
@@ -391,46 +401,51 @@ class Solver:
             return (collector.snapshot()
                     if collector is not None and cfg.metrics else None)
 
-        def run_round(fn, lanes, round_no):
-            """Round ``round_no``: feed, dispatch, read back, collect."""
+        # The host takes each round itself while it has a use for it.
+        each_round = self.on_event is not None or collector is not None
+
+        def dispatch(fn, lanes, rounds, limit):
+            """Rounds ``rounds + 1`` on, at most ``limit``, in one
+            dispatch: feed, dispatch, read back, collect."""
             fed = bool(pool)
             lanes = feed_pool(lanes)
+            budget = 1 if each_round or pool else limit
             if collector is not None:
                 collector.before_round(lanes, dirty=fed)
             with obs.span("repro.solve.dispatch"):
-                lanes, open_work = fn(lanes)
+                lanes, open_work, ran = fn(lanes, np.int32(budget))
             with obs.span("repro.solve.readback"):
-                open_now = int(jnp.sum(open_work))
+                open_work, ran = jax.device_get((open_work, ran))
+            open_now, rounds = int(np.sum(open_work)), rounds + int(ran)
             if collector is not None:
-                collector.after_round(round_no, lanes, open_now)
-            return lanes, open_now
+                collector.after_round(rounds, lanes, open_now)
+            return lanes, open_now, rounds
 
         rounds, done = 0, False
-        for _ in range(bootstrap_rounds):
-            rounds += 1
+        while not done and rounds < bootstrap_rounds:
             with obs.span("repro.solve.round"):
-                lanes, open_now = run_round(boot_fn or round_fn, lanes,
-                                            rounds)
-            if open_now == 0 and not pool:
-                done = True
-                break
+                lanes, open_now, rounds = dispatch(
+                    boot_fn, lanes, rounds, bootstrap_rounds - rounds)
+            done = open_now == 0 and not pool
+        every = cfg.checkpoint_every
         while not done and rounds < cfg.max_rounds:
-            rounds += 1
+            limit = cfg.max_rounds - rounds
+            if every:
+                limit = min(limit, every - rounds % every)
             with obs.span("repro.solve.round"):
-                lanes, open_now = run_round(round_fn, lanes, rounds)
+                lanes, open_now, rounds = dispatch(round_fn, lanes, rounds,
+                                                   limit)
                 if self.on_event is not None:
                     # The incumbent readback costs a device sync — only pay
                     # it when someone is listening.
                     emit(self.on_event, "round", round=rounds,
                          open_work=open_now, best=int(jnp.min(lanes.best)),
                          lanes=lanes, metrics=snap())
-                if (cfg.checkpoint_every and cfg.checkpoint_path
-                        and rounds % cfg.checkpoint_every == 0):
+                if every and rounds % every == 0:
                     ckpt.save(cfg.checkpoint_path, _gather_lanes(lanes))
                     emit(self.on_event, "checkpoint", round=rounds,
                          path=cfg.checkpoint_path)
-            if open_now == 0 and not pool:
-                done = True
+            done = open_now == 0 and not pool
 
         with obs.span("repro.solve.finish"):
             stats = SolveStats(

@@ -95,6 +95,24 @@ def test_solve_writes_its_phases_into_the_profiler_trace(tmp_path,
     assert first_dispatch[0] <= t0 and t1 <= first_dispatch[1]
 
 
+@pytest.mark.parametrize("bootstrap_rounds", [0, 2])
+def test_a_plain_solve_is_one_dispatch_and_one_more_for_its_bootstrap(
+        tmp_path, bootstrap_rounds):
+    """With nothing that takes each round back, every main round runs in
+    one dispatch of the device loop, and the bootstrap rounds in one
+    more; each dispatch has its ``repro.solve.round`` span."""
+    solver = Solver(SolverConfig(lanes=8, steps_per_round=16,
+                                 bootstrap_rounds=bootstrap_rounds))
+    with jax.profiler.trace(str(tmp_path)):
+        res = solver.solve(registry.problem("vc", "gnp:30:20:3"))
+    spans = _host_spans(tmp_path)
+    assert res.stats.rounds > bootstrap_rounds + 1
+    dispatches = 1 + (bootstrap_rounds > 0)
+    for name in ("repro.solve.round", "repro.solve.dispatch",
+                 "repro.solve.readback", "repro.round.trace"):
+        assert len(spans[name]) == dispatches, name
+
+
 def test_round_traces_counts_each_call_and_only_with_metrics():
     solver = Solver(SolverConfig(lanes=8, steps_per_round=16, metrics=True))
     for _ in range(2):

@@ -14,6 +14,7 @@ compilation cache is off around the compiles, since an entry written for
 a described chip cannot be read back without one.
 """
 
+import collections
 import re
 
 import jax
@@ -24,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro import registry
-from repro.core.distributed import (lane_partition_specs,
+from repro.core.distributed import (jit_round_loop, lane_partition_specs,
                                     make_distributed_round, make_round)
 from repro.core.engine import init_lanes
 from repro.kernels import autotune, bitset_ops
@@ -244,3 +245,80 @@ def test_vc_round_replay_keeps_the_stack_in_place(one_chip):
             looped.append(comp)
     assert entry is not None
     assert all(c == entry for c in looped), looped
+
+
+def _computations(text):
+    """name -> instruction lines of each computation of an HLO module,
+    and the entry computation's name."""
+    comps, entry, comp = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            comp = head.group(2)
+            comps[comp] = []
+            entry = comp if head.group(1) else entry
+        elif comp is not None:
+            comps[comp].append(line)
+    return comps, entry
+
+
+def _reachable(comps, roots):
+    """The computations that ``roots`` call, directly or not."""
+    seen, todo = set(), list(roots)
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            todo += re.findall(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)",
+                               line)
+            for group in re.findall(
+                    r"(?:branch_computations|called_computations)=\{([^}]*)\}",
+                    line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def test_cell_mesh_loop_keeps_the_collectives_in_the_loop(mesh4):
+    """The four-chip benchmark loop (vc, G(150, 0.10), 768 lanes a chip,
+    64 steps) compiles for the described host as one outer loop of
+    rounds: every all-gather (the steal) and all-reduce (the election,
+    open work) of the round runs inside that loop's body."""
+    prob = make_vertex_cover(registry.get("vc").parse("gnp:150:10:1"))
+    specs = lane_partition_specs(prob, mesh4.axis_names)
+    lanes = jax.eval_shape(lambda: init_lanes(prob, 4 * 768))
+    args = jax.tree_util.tree_map(
+        lambda l, s: _spec(l.shape, l.dtype, NamedSharding(mesh4, s)),
+        lanes, specs)
+    budget = _spec((), jnp.int32, NamedSharding(mesh4, P()))
+    loop = jit_round_loop(make_round(prob, 64, mesh4.axis_names), prob, mesh4)
+    text = loop.lower(args, budget).compile().as_text()
+    assert re.match(r"HloModule jit_round_fn\b", text)
+    comps, entry = _computations(text)
+    bodies = [b for line in comps[entry] if " while(" in line
+              for b in re.findall(r"body=%([\w.\-]+)", line)]
+    assert len(bodies) == 1, bodies
+    inside = _reachable(comps, bodies)
+    found = collections.Counter(
+        (m.group(1), comp in inside) for comp, lines in comps.items()
+        for line in lines
+        for m in [re.search(r"\b(all-gather|all-reduce)(?:-start)?\(", line)]
+        if m)
+    assert found[("all-gather", True)] >= 2
+    assert found[("all-reduce", True)] >= 4
+    assert not found[("all-gather", False)] and not found[("all-reduce", False)]
+
+
+def test_vc_loop_keeps_the_stack_in_place(one_chip):
+    """The one-chip benchmark loop (vc, G(125, 0.10), 768 lanes, 64
+    steps): no copy makes a ``[768, 127, 4]`` stack leaf, in the loop or
+    around it."""
+    prob = make_vertex_cover(registry.get("vc").parse("gnp:125:10:1"))
+    lanes = jax.eval_shape(lambda: init_lanes(prob, 768))
+    text = jit_round_loop(make_round(prob, 64), prob).lower(
+        _shapes(lanes, one_chip), _spec((), jnp.int32, one_chip)
+    ).compile().as_text()
+    assert re.match(r"HloModule jit_round_fn\b", text)
+    assert " while(" in text
+    assert not re.search(r"%copy[.\d]* = \w+\[768,127,4\]", text)
