@@ -41,8 +41,6 @@ from repro import registry
 from repro.core.api import INF_VALUE
 from repro.kernels import bitset_ops, ref
 from repro.kernels.bitset_degree import degree_argmax
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ssd_scan import ssd_scan
 from repro.problems.dominating_set import DSState
 from repro.problems.graphs import gnp_graph, full_mask, num_words
 from repro.problems.vertex_cover import VCState, make_vertex_cover_callbacks
@@ -56,43 +54,6 @@ BENCH_JSON = os.path.normpath(os.path.join(
 
 def run(quick: bool = False) -> list:
     rows = []
-    key = jax.random.PRNGKey(0)
-
-    # flash attention
-    for (b, s, h, g, hd) in [(1, 512, 8, 2, 64)] + \
-            ([] if quick else [(2, 1024, 8, 8, 128)]):
-        ks = jax.random.split(key, 3)
-        q = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32) * 0.5
-        k = jax.random.normal(ks[1], (b, s, g, hd), jnp.float32) * 0.5
-        v = jax.random.normal(ks[2], (b, s, g, hd), jnp.float32) * 0.5
-        fn = jax.jit(lambda q, k, v: ref.flash_attention_ref(q, k, v))
-        t, out_ref = timed(lambda: np.asarray(fn(q, k, v)))
-        out_pl = flash_attention(q, k, v, interpret=True)
-        err = float(jnp.max(jnp.abs(out_pl - out_ref)))
-        rows.append({"kernel": "flash_attention",
-                     "shape": f"b{b}_s{s}_h{h}_g{g}_d{hd}",
-                     "ref_ms": round(t * 1e3, 2),
-                     "max_abs_err": f"{err:.2e}"})
-
-    # ssd scan
-    for (b, s, h, p, n, chunk) in [(1, 256, 4, 64, 64, 64)] + \
-            ([] if quick else [(2, 512, 8, 64, 128, 128)]):
-        ks = jax.random.split(key, 5)
-        x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32) * 0.5
-        dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h))) * 0.5
-        a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
-        bb = jax.random.normal(ks[3], (b, s, 1, n)) * 0.3
-        cc = jax.random.normal(ks[4], (b, s, 1, n)) * 0.3
-        d = jnp.ones((h,), jnp.float32)
-        fn = jax.jit(lambda *args: ref.ssd_scan_ref(*args, chunk=chunk)[0])
-        t, out_ref = timed(lambda: np.asarray(fn(x, dt, a, bb, cc, d)))
-        out_pl, _ = ssd_scan(x, dt, a, bb, cc, d, chunk=chunk,
-                             interpret=True)
-        err = float(jnp.max(jnp.abs(out_pl - out_ref)))
-        rows.append({"kernel": "ssd_scan",
-                     "shape": f"b{b}_s{s}_h{h}_p{p}_n{n}_c{chunk}",
-                     "ref_ms": round(t * 1e3, 2),
-                     "max_abs_err": f"{err:.2e}"})
 
     # bitset degree/argmax
     for (n, pr, lanes) in [(300, 0.05, 16)] + ([] if quick else

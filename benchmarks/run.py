@@ -4,7 +4,6 @@
   table2  — PARALLEL-DOMINATING-SET scaling (paper Table II)
   fig10   — T_S/T_R steal-traffic gap growth (paper Fig. 10)
   kernels — Pallas kernel micro (shapes, ref timings, interpret deltas)
-  roofline— aggregated dry-run roofline table (EXPERIMENTS.md §Roofline)
   service — continuous-batching throughput vs sequential solves
   latency — scheduling policies on a Poisson trace (p50/p95, deadlines)
 
@@ -21,7 +20,7 @@ import subprocess
 import sys
 import time
 
-from benchmarks import (fig10_steal_traffic, kernel_micro, roofline_table,
+from benchmarks import (fig10_steal_traffic, kernel_micro,
                         service_latency, service_throughput,
                         table1_vertex_cover, table2_dominating_set)
 from benchmarks.common import ART_DIR
@@ -32,7 +31,6 @@ SUITES = [
     ("table2", table2_dominating_set.main),
     ("fig10", fig10_steal_traffic.main),
     ("kernels", kernel_micro.main),
-    ("roofline", roofline_table.main),
     ("service", service_throughput.main),
     ("latency", service_latency.main),
 ]

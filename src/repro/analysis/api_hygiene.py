@@ -46,12 +46,6 @@ def _parse_snapshot(text: str) -> Dict[str, Set[str]]:
     return sections
 
 
-def _module_rel(dotted: str) -> List[str]:
-    """Candidate repo-relative paths for a dotted module."""
-    base = "src/" + dotted.replace(".", "/")
-    return [f"{base}/__init__.py", f"{base}.py"]
-
-
 @register
 class ApiHygieneRule(Rule):
     name = "api-hygiene"

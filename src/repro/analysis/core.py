@@ -13,9 +13,8 @@ Design (DESIGN.md §10):
     rule for that line.  The reason is mandatory — one without it is
     itself an error (rule ``suppression``), so every deliberate
     violation is documented in place.
-  * Idle seed modules (``models/``, ``train/``, ... — see
-    :data:`IDLE_SEED_ALLOWLIST`) are excluded from the enforced surface
-    until ROADMAP Open item 3 wires them into the engine.
+  * Every ``.py`` file under the given paths is analyzed; nothing is
+    skipped.
 
 Everything here is stdlib-only; rules must not import jax at module
 scope (the CI lint job runs without accelerator deps installed).
@@ -27,11 +26,10 @@ import ast
 import dataclasses
 import pathlib
 import re
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Type
+from typing import Dict, List, Optional, Sequence, Tuple, Type
 
 __all__ = [
     "Finding",
-    "IDLE_SEED_ALLOWLIST",
     "LintResult",
     "Module",
     "RepoContext",
@@ -40,24 +38,6 @@ __all__ = [
     "lint_paths",
     "register",
 ]
-
-#: Seed modules that exist in the tree but are not wired into the
-#: engine yet (ROADMAP Open item 3).  Relative to the lint root's
-#: ``src/repro`` package directory (or any analyzed path); matched as
-#: path suffixes so the list works both for ``src`` runs and fixtures.
-IDLE_SEED_ALLOWLIST: Tuple[str, ...] = (
-    "models/",
-    "train/",
-    "configs/",
-    "data/",
-    "serve/",
-    "distributed/",
-    "kernels/flash_attention.py",
-    "kernels/ssd_scan.py",
-    "launch/train.py",
-    "launch/serve.py",
-    "launch/dryrun.py",
-)
 
 _SUPPRESS_RE = re.compile(
     r"#\s*repro-lint:\s*disable=([A-Za-z0-9_,-]+)"
@@ -290,47 +270,20 @@ def all_rules() -> Dict[str, Type[Rule]]:
 class LintResult:
     findings: List[Finding]
     files: int
-    skipped: List[str]      # allowlisted files that were not analyzed
 
     @property
     def errors(self) -> List[Finding]:
         return [f for f in self.findings if f.severity == "error"]
 
 
-def _is_allowlisted(rel: str) -> bool:
-    norm = rel.replace("\\", "/")
-    for entry in IDLE_SEED_ALLOWLIST:
-        if entry.endswith("/"):
-            if f"/{entry}" in f"/{norm}":
-                return True
-        elif norm.endswith(entry):
-            return True
-    return False
-
-
 def _collect_files(root: pathlib.Path,
-                   paths: Sequence[str]) -> Tuple[List[pathlib.Path],
-                                                  List[str]]:
+                   paths: Sequence[str]) -> List[pathlib.Path]:
     files: List[pathlib.Path] = []
-    skipped: List[str] = []
     for p in paths:
         path = (root / p) if not pathlib.Path(p).is_absolute() \
             else pathlib.Path(p)
-        if path.is_file():
-            candidates: Iterable[pathlib.Path] = [path]
-        else:
-            candidates = sorted(path.rglob("*.py"))
-        for f in candidates:
-            try:
-                rel = str(f.resolve().relative_to(root.resolve()))
-            except ValueError:
-                rel = str(f)
-            rel = rel.replace("\\", "/")
-            if _is_allowlisted(rel):
-                skipped.append(rel)
-            else:
-                files.append(f)
-    return files, skipped
+        files.extend([path] if path.is_file() else sorted(path.rglob("*.py")))
+    return files
 
 
 def _suppression_findings(mod: Module) -> List[Finding]:
@@ -359,7 +312,7 @@ def lint_paths(paths: Sequence[str],
     ``result.errors``."""
     if root is None:
         root = pathlib.Path(__file__).resolve().parents[3]
-    files, skipped = _collect_files(root, paths)
+    files = _collect_files(root, paths)
 
     modules: List[Module] = []
     findings: List[Finding] = []
@@ -397,5 +350,4 @@ def lint_paths(paths: Sequence[str],
         findings.extend(cls().run(ctx))
 
     findings.sort(key=lambda f: (f.path, f.line, f.rule))
-    return LintResult(findings=findings, files=len(modules),
-                      skipped=skipped)
+    return LintResult(findings=findings, files=len(modules))

@@ -8,7 +8,8 @@ Layout (DESIGN.md §5):
                           popcount/row-reduce primitives every problem
                           family binds to;
   ``bitset_degree.py``  — vertex cover's binding of that library;
-  ``flash_attention.py``/``ssd_scan.py`` — model-side kernels;
+  ``autotune.py``       — tile/stages choice per shape and device, and
+                          the interpret-mode rule (DESIGN.md §5.6);
   ``ops.py``            — jitted dispatchers (Pallas off the CPU, jnp
                           oracle on it; interpret mode on the CPU only);
   ``ref.py``            — the pure-jnp oracles each kernel is validated

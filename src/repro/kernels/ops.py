@@ -10,53 +10,20 @@ compiles or the call raises.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import bitset_ops, ref
-from repro.kernels.autotune import resolve_interpret
 from repro.kernels.bitset_degree import degree_argmax as _degree_pallas
 from repro.kernels.bitset_degree import degree_stats as _degree_stats_pallas
-from repro.kernels.flash_attention import flash_attention as _flash_pallas
-from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 
 def _use_pallas(use_pallas: Optional[bool]) -> bool:
     if use_pallas is None:
         return jax.default_backend() != "cpu"
     return use_pallas
-
-
-@partial(jax.jit, static_argnames=("window", "softcap", "query_scale",
-                                   "block_q", "block_k", "use_pallas",
-                                   "interpret"))
-def flash_attention(q, k, v, *, window: Optional[int] = None,
-                    softcap: float = 0.0,
-                    query_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
-                    use_pallas: Optional[bool] = None,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
-    if _use_pallas(use_pallas):
-        return _flash_pallas(q, k, v, window=window, softcap=softcap,
-                             query_scale=query_scale, block_q=block_q,
-                             block_k=block_k,
-                             interpret=resolve_interpret(interpret))
-    return ref.flash_attention_ref(q, k, v, window=window, softcap=softcap,
-                                   query_scale=query_scale,
-                                   block_q=block_q, block_k=block_k)
-
-
-@partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret"))
-def ssd_scan(x, dt, a, b, c, d, *, chunk: int = 64,
-             use_pallas: Optional[bool] = None,
-             interpret: Optional[bool] = None
-             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if _use_pallas(use_pallas):
-        return _ssd_pallas(x, dt, a, b, c, d, chunk=chunk,
-                           interpret=resolve_interpret(interpret))
-    return ref.ssd_scan_ref(x, dt, a, b, c, d, chunk=chunk)
 
 
 @partial(jax.jit, static_argnames=("tile", "stages", "use_pallas",

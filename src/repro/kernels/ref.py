@@ -1,7 +1,5 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose references).
 
-flash_attention -> repro.models.attention.blocked_attention
-ssd_scan        -> repro.models.ssm.ssd_chunked
 bitset_ops      -> count_stats / stacked_count_stats / popcount_reduce /
                    masked_row_reduce / domination_stats below
                    (DESIGN.md §5.2's contract, stated in plain jnp)
@@ -13,13 +11,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from repro.models.attention import blocked_attention as flash_attention_ref  # noqa: F401
-from repro.models.ssm import ssd_chunked
-
-
-def ssd_scan_ref(x, dt, a, b, c, d, chunk: int = 64):
-    return ssd_chunked(x, dt, a, b, c, d, chunk=chunk)
 
 
 def _bit_set(mask: jnp.ndarray, n: int) -> jnp.ndarray:

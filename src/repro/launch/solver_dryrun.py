@@ -3,7 +3,8 @@
 Lowers one distributed steal round (expand R nodes -> intra-device steal
 -> cross-device steal -> incumbent pmin -> termination psum) for a
 512-vertex Vertex Cover instance over the 16x16 (256-chip) and 2x16x16
-(512-chip) meshes, and runs the same roofline analysis as the LM cells.
+(512-chip) meshes, and runs the roofline analysis of ``repro.roofline``
+on its compiled HLO.
 
 This quantifies the paper's central claim at pod scale: tasks are O(d)
 int8 index vectors, so the steal phase's collective payload is tiny
@@ -27,10 +28,13 @@ import numpy as np
 from repro import registry
 from repro.core.distributed import make_distributed_round
 from repro.core.engine import init_lanes
-from repro.launch.dryrun import ARTIFACT_DIR
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
                                make_production_mesh)
 from repro.roofline import analyze_hlo
+
+ARTIFACT_DIR = os.path.join(os.path.dirname(__file__),
+                            "..", "..", "..", "benchmarks", "artifacts",
+                            "dryrun")
 
 
 def run(multi_pod: bool, lanes_per_device: int = 8,

@@ -202,20 +202,12 @@ class RooflineCounts:
     collective_bytes: float = 0.0
     per_collective: Dict[str, float] = dataclasses.field(default_factory=dict)
     dots: int = 0
-    #: bytes of score-block-shaped tensors (two adjacent equal dims >= 128):
-    #: the attention/SSD quadratic intermediates.  Pure-XLA blocked
-    #: attention streams them through HBM; the Pallas flash/SSD kernels
-    #: (repro.kernels) keep them in VMEM, so the *kernel-adjusted* memory
-    #: term subtracts them.  Both are reported.
-    score_bytes: float = 0.0
 
     def terms(self, peak_flops: float, hbm_bw: float, ici_bw: float
               ) -> Dict[str, float]:
         return {
             "compute_s": self.flops / peak_flops,
             "memory_s": self.hbm_bytes / hbm_bw,
-            "memory_kernel_adj_s": max(self.hbm_bytes - self.score_bytes, 0.0)
-            / hbm_bw,
             "collective_s": self.collective_bytes / ici_bw,
         }
 
@@ -388,35 +380,5 @@ def analyze_hlo(hlo: str) -> RooflineCounts:
             if (in_fusion or op.opcode in _SKIP_BYTES_OPS or is_coll
                     or op.opcode in _FUSED_ELEMENTWISE):
                 continue
-            b = m * _op_hbm_bytes(op, comp, comps)
-            counts.hbm_bytes += b
-            if _in_kernel_region(op, comps):
-                counts.score_bytes += b
+            counts.hbm_bytes += m * _op_hbm_bytes(op, comp, comps)
     return counts
-
-
-#: einsum labels unique to the attention / SSD inner blocks (the regions a
-#: Pallas kernel replaces); ops whose metadata op_name descends from them
-#: are intra-kernel traffic.
-_KERNEL_MARKERS = ("bqgrd", "bgrqk", "bcihn", "bcijh", "bcjhp", "bchnp")
-
-
-def _in_kernel_region(op: Op, comps: Dict[str, Computation]) -> bool:
-    if any(k in op.rest for k in _KERNEL_MARKERS):
-        return True
-    if op.opcode == "fusion":
-        cm = _CALL_RE.search(op.rest)
-        callee = comps.get(cm.group(1)) if cm else None
-        if callee and any(any(k in o.rest for k in _KERNEL_MARKERS)
-                          for o in callee.ops):
-            return True
-    dims = _shape_dims(op.result_type)
-    return any(dims[i] == dims[i + 1] and dims[i] >= 128
-               for i in range(len(dims) - 1))
-
-
-def model_flops(cfg, tokens: int, is_train: bool) -> float:
-    """MODEL_FLOPS = 6·N_active·D tokens (2 fwd + 4 bwd per param-token);
-    serving counts 2·N_active·D."""
-    n = cfg.active_param_count()
-    return (6.0 if is_train else 2.0) * n * tokens

@@ -1,7 +1,7 @@
 """Continuous-batching solver service over one lane pool.
 
-The serving pattern of ``repro.serve.driver`` (fixed slot pool, lockstep
-ticks, admission/retirement at tick boundaries) applied to backtracking:
+The continuous-batching serving pattern (fixed slot pool, lockstep ticks,
+admission/retirement at tick boundaries) applied to backtracking:
 
   * the *pool* is W engine lanes advancing in lockstep under one jitted
     round (expand → instance-scoped steal → per-instance termination);

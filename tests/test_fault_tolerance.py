@@ -3,17 +3,14 @@
 * Straggler mitigation IS the paper's contribution: when one lane starts
   with all the work (maximal skew), steal rounds must spread it — the
   node count processed by the initially-idle lanes must dominate.
-* Elastic training restore: a checkpoint written under one mesh must
-  restore under a different device count with different shardings.
-* Serving driver: batched lockstep decode equals unbatched decoding.
+* The solver's checkpoint is a few KB, and a mesh-sharded service killed
+  mid-solve resumes on a different device count with the same answers.
 """
 
 import os
 import subprocess
 import sys
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -55,95 +52,6 @@ def test_solver_checkpoint_is_tiny():
     path = os.path.join(tempfile.mkdtemp(), "s.ckpt")
     ckpt.save(path, lanes)
     assert os.path.getsize(path) < 64 * 1024     # < 64 KB for 64 lanes
-
-
-_ELASTIC = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import configs
-from repro.models import model as M
-from repro.train.checkpoint import save, restore
-from repro.train.optim import adamw_init
-from repro.train.step import master_params
-
-cfg = configs.smoke("qwen2-7b")
-params = master_params(cfg, M.init(cfg, jax.random.PRNGKey(0)))
-opt = adamw_init(params)
-
-# place under an 8-device mesh, checkpoint
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-specs = M.specs(cfg, mesh8.axis_names, M.mesh_axis_sizes(mesh8))
-sh8 = jax.tree_util.tree_map(lambda s: NamedSharding(mesh8, s), specs,
-                             is_leaf=lambda x: isinstance(x, P))
-params8 = jax.tree_util.tree_map(jax.device_put, params, sh8)
-save("/tmp/elastic.ckpt", params8, opt, step=5)
-
-# restore under a DIFFERENT mesh (2x2 = "shrunk cluster")
-mesh4 = jax.make_mesh((2, 2), ("data", "model"),
-                      devices=jax.devices()[:4])
-specs4 = M.specs(cfg, mesh4.axis_names, M.mesh_axis_sizes(mesh4))
-sh4 = jax.tree_util.tree_map(lambda s: NamedSharding(mesh4, s), specs4,
-                             is_leaf=lambda x: isinstance(x, P))
-opt_sh4 = type(opt)(m=sh4, v=sh4)
-p4, o4, step = restore("/tmp/elastic.ckpt", params, opt,
-                       shardings=(sh4, opt_sh4))
-assert step == 5
-for a, b in zip(jax.tree_util.tree_leaves(params),
-                jax.tree_util.tree_leaves(p4)):
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-print("ELASTIC_OK")
-"""
-
-
-@pytest.mark.slow
-def test_elastic_train_restore_across_meshes():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run([sys.executable, "-c", _ELASTIC], env=env,
-                          capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    assert "ELASTIC_OK" in proc.stdout
-
-
-@pytest.mark.slow
-def test_batched_server_matches_reference():
-    from repro import configs
-    from repro.models import model as M
-    from repro.serve.driver import BatchedServer, Request
-
-    cfg = configs.smoke("glm4-9b")
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    plen, n_new = 12, 5
-    key = jax.random.PRNGKey(2)
-    prompts = [np.asarray(jax.random.randint(jax.random.fold_in(key, i),
-                                             (plen,), 0, cfg.vocab))
-               for i in range(3)]
-    reqs = [Request(rid=i, prompt=p, max_new=n_new)
-            for i, p in enumerate(prompts)]
-    server = BatchedServer(cfg, params, batch_slots=2,
-                           max_seq=plen + n_new + 1, block=4)
-    server.run(reqs)
-    assert all(len(r.out) == n_new for r in reqs)
-
-    # unbatched reference for request 0
-    from repro.serve.engine import (greedy_sample, make_decode_step,
-                                    make_prefill_step)
-    prefill = make_prefill_step(cfg, block_q=4, block_k=4)
-    decode = make_decode_step(cfg)
-    logits, cache = prefill(params, {"tokens": jnp.asarray(prompts[0])[None]})
-    cache = M.pad_cache(cfg, cache, plen + n_new + 1)
-    tok = greedy_sample(logits).reshape(1, 1)
-    ref = []
-    pos = plen
-    for _ in range(n_new):
-        logits, cache = decode(params, cache, tok, jnp.int32(pos))
-        tok = greedy_sample(logits).reshape(1, 1)
-        ref.append(int(tok[0, 0]))
-        pos += 1
-    assert reqs[0].out == ref
 
 
 _MESH_SERVICE_KILL = r"""

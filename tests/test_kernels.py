@@ -1,8 +1,14 @@
 """Pallas kernel validation: interpret=True vs pure-jnp oracles.
 
-Per the harness rules each kernel is swept over shapes/dtypes and
-assert_allclose'd against its ref.py oracle.
+Each kernel is swept over shapes and compared exactly against its ref.py
+oracle.  The kernel layer also depends on nothing above it: importing
+``repro.kernels.ops`` loads no model, training or decoding code.
 """
+
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -11,117 +17,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.bitset_degree import degree_argmax, degree_stats
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ssd_scan import ssd_scan
 from repro.problems.graphs import gnp_graph
-
-
-# ---------------------------------------------------------------------------
-# flash attention
-# ---------------------------------------------------------------------------
-
-ATTN_CASES = [
-    # (b, s, h, g, hd, window, softcap, dtype)
-    (1, 256, 4, 4, 64, None, 0.0, jnp.float32),
-    (2, 256, 4, 2, 64, None, 0.0, jnp.bfloat16),
-    (1, 512, 8, 2, 64, None, 0.0, jnp.float32),
-    (1, 256, 2, 1, 128, None, 0.0, jnp.float32),
-    (2, 512, 4, 4, 64, 128, 0.0, jnp.float32),      # sliding window
-    (1, 256, 4, 2, 64, None, 50.0, jnp.float32),    # softcap (gemma2)
-    (1, 512, 4, 1, 64, 256, 30.0, jnp.bfloat16),    # window + softcap
-]
-
-
-@pytest.mark.parametrize("b,s,h,g,hd,window,softcap,dtype", ATTN_CASES)
-def test_flash_attention_matches_ref(b, s, h, g, hd, window, softcap, dtype):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = (jax.random.normal(k1, (b, s, h, hd)) * 0.5).astype(dtype)
-    k = (jax.random.normal(k2, (b, s, g, hd)) * 0.5).astype(dtype)
-    v = (jax.random.normal(k3, (b, s, g, hd)) * 0.5).astype(dtype)
-    got = flash_attention(q, k, v, window=window, softcap=softcap,
-                          block_q=128, block_k=128, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, window=window, softcap=softcap,
-                                   block_q=128, block_k=128)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-def test_flash_attention_block_shape_sweep():
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(k1, (1, 512, 4, 64), jnp.float32) * 0.5
-    k = jax.random.normal(k2, (1, 512, 2, 64), jnp.float32) * 0.5
-    v = jax.random.normal(k3, (1, 512, 2, 64), jnp.float32) * 0.5
-    want = ref.flash_attention_ref(q, k, v)
-    for bq, bk in [(128, 128), (256, 128), (128, 256), (256, 256),
-                   (512, 512)]:
-        got = flash_attention(q, k, v, block_q=bq, block_k=bk,
-                              interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5,
-                                   err_msg=f"bq={bq} bk={bk}")
-
-
-# ---------------------------------------------------------------------------
-# SSD scan
-# ---------------------------------------------------------------------------
-
-SSD_CASES = [
-    # (b, s, h, p, g, n, chunk, dtype)
-    (1, 128, 2, 64, 1, 64, 64, jnp.float32),
-    (2, 256, 4, 64, 1, 128, 64, jnp.float32),
-    (1, 256, 4, 64, 2, 64, 128, jnp.float32),
-    (2, 128, 2, 32, 1, 32, 32, jnp.bfloat16),
-]
-
-
-@pytest.mark.parametrize("b,s,h,p,g,n,chunk,dtype", SSD_CASES)
-def test_ssd_scan_matches_ref(b, s, h, p, g, n, chunk, dtype):
-    keys = jax.random.split(jax.random.PRNGKey(1), 5)
-    x = (jax.random.normal(keys[0], (b, s, h, p)) * 0.5).astype(dtype)
-    dt = jax.nn.softplus(jax.random.normal(keys[1], (b, s, h))) * 0.5
-    a = -jnp.exp(jax.random.normal(keys[2], (h,)) * 0.3)
-    bb = (jax.random.normal(keys[3], (b, s, g, n)) * 0.3).astype(dtype)
-    cc = (jax.random.normal(keys[4], (b, s, g, n)) * 0.3).astype(dtype)
-    d = jnp.ones((h,), jnp.float32)
-    y, st = ssd_scan(x, dt, a, bb, cc, d, chunk=chunk, interpret=True)
-    y_ref, st_ref = ref.ssd_scan_ref(x, dt, a, bb, cc, d, chunk=chunk)
-    tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(y_ref, np.float32),
-                               rtol=tol, atol=tol)
-    np.testing.assert_allclose(np.asarray(st), np.asarray(st_ref),
-                               rtol=tol, atol=tol)
-
-
-def test_ssd_state_continuity():
-    """Final state from the kernel must continue a decode stream exactly."""
-    from repro.models.ssm import ssd_decode_step
-    b, s, h, p, g, n = 1, 128, 2, 32, 1, 32
-    keys = jax.random.split(jax.random.PRNGKey(3), 6)
-    x = jax.random.normal(keys[0], (b, s, h, p)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(keys[1], (b, s, h))) * 0.5
-    a = -jnp.exp(jax.random.normal(keys[2], (h,)) * 0.3)
-    bb = jax.random.normal(keys[3], (b, s, g, n)) * 0.3
-    cc = jax.random.normal(keys[4], (b, s, g, n)) * 0.3
-    d = jnp.ones((h,), jnp.float32)
-    _, st = ssd_scan(x, dt, a, bb, cc, d, chunk=64, interpret=True)
-    # one more token via the decode step vs a longer chunked run
-    xt = jax.random.normal(keys[5], (b, h, p)) * 0.5
-    dt_t = jnp.full((b, h), 0.3)
-    bt = jnp.ones((b, g, n)) * 0.1
-    ct = jnp.ones((b, g, n)) * 0.1
-    y_dec, st_dec = ssd_decode_step(st, xt, dt_t, a, bt, ct, d)
-    x2 = jnp.concatenate([x, xt[:, None]], axis=1)
-    dt2 = jnp.concatenate([dt, dt_t[:, None]], axis=1)
-    b2 = jnp.concatenate([bb, bt[:, None]], axis=1)
-    c2 = jnp.concatenate([cc, ct[:, None]], axis=1)
-    y2, st2 = ref.ssd_scan_ref(x2, dt2, a, b2, c2, d, chunk=43)
-    np.testing.assert_allclose(np.asarray(y_dec), np.asarray(y2[:, -1]),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(st_dec), np.asarray(st2),
-                               rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -206,3 +102,31 @@ def test_degree_stats_all_dead_and_vmap():
         lambda m: degree_stats(adj, m[None, :], tile=32, interpret=True)[0]
     )(alive)
     np.testing.assert_array_equal(np.asarray(mapped), np.asarray(flat))
+
+
+# ---------------------------------------------------------------------------
+# layering
+# ---------------------------------------------------------------------------
+
+_IMPORT_PROBE = """
+import importlib.util, json, sys
+import repro.kernels.ops, repro.kernels.ref
+gone = tuple("repro." + name for name in ("models", "train", "serve"))
+print(json.dumps({
+    "loaded": sorted(m for m in sys.modules
+                     if any(m == g or m.startswith(g + ".") for g in gone)),
+    "importable": [g for g in gone if importlib.util.find_spec(g)],
+}))
+"""
+
+
+def test_kernel_layer_imports_nothing_above_it():
+    """A fresh interpreter imports the dispatchers and their oracles and
+    loads no module of a language-model stack; no such package exists."""
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    proc = subprocess.run([sys.executable, "-c", _IMPORT_PROBE], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got == {"loaded": [], "importable": []}

@@ -164,9 +164,8 @@ def test_snapshot_clause_clean_when_synced(tmp_path):
 def test_full_repo_is_clean():
     result = lint_paths(["src"], root=ROOT)
     assert result.errors == [], [f.format() for f in result.errors]
-    assert result.files > 30
-    # idle seed modules stay allowlisted until ROADMAP Open item 3
-    assert result.skipped, "expected allowlisted seed modules"
+    # Nothing is skipped: every module under src/ is analyzed.
+    assert result.files == len(list((ROOT / "src").rglob("*.py")))
 
 
 def test_registry_has_all_four_packs():

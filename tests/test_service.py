@@ -23,6 +23,7 @@ from repro.core.serial import serial_rb
 from repro.problems import (gnp_graph, make_dominating_set_py,
                             make_vertex_cover_py, random_regularish_graph)
 from _legacy import legacy_service
+from _solutions import assert_feasible
 from repro.service import SolveRequest, SolverService
 from repro.service.batch_problem import StackedSpec, pack_instance
 
@@ -44,31 +45,6 @@ def oracle(family, graph):
 ORACLES = [oracle(f, g) for f, g in MIX]
 
 
-def bits_of(mask: np.ndarray):
-    out = set()
-    for word_i, word in enumerate(np.asarray(mask, np.uint32)):
-        for b in range(32):
-            if (int(word) >> b) & 1:
-                out.add(word_i * 32 + b)
-    return out
-
-
-def assert_valid_payload(family, graph, payload, optimum):
-    """The payload must be an actual optimal solution, not just a size."""
-    chosen = bits_of(payload)
-    assert len(chosen) == optimum, (family, graph.name, chosen)
-    assert all(v < graph.n for v in chosen)
-    if family == "vc":
-        for u in range(graph.n):
-            for v in bits_of(graph.adj[u]):
-                assert u in chosen or v in chosen, (graph.name, u, v)
-    else:
-        dominated = set()
-        for v in chosen:
-            dominated |= {v} | bits_of(graph.adj[v])
-        assert dominated >= set(range(graph.n)), (graph.name, dominated)
-
-
 def run_requests(svc):
     reqs = [SolveRequest(rid=i, graph=g, family=f)
             for i, (f, g) in enumerate(MIX)]
@@ -84,8 +60,8 @@ def test_service_matches_serial_oracles(lanes):
     _, results = run_requests(svc)
     for i, (family, graph) in enumerate(MIX):
         assert results[i].optimum == ORACLES[i], (i, family, graph.name)
-        assert_valid_payload(family, graph, results[i].payload,
-                             results[i].optimum)
+        assert_feasible(family, graph, results[i].payload,
+                        results[i].optimum)
 
 
 # -- kernel backend: pallas stacked evaluate == jnp stacked evaluate ----------
@@ -140,8 +116,8 @@ def test_service_pallas_backend_matches_serial_oracles():
     _, results = run_requests(svc)
     for i, (family, graph) in enumerate(MIX):
         assert results[i].optimum == ORACLES[i], (i, family, graph.name)
-        assert_valid_payload(family, graph, results[i].payload,
-                             results[i].optimum)
+        assert_feasible(family, graph, results[i].payload,
+                        results[i].optimum)
 
 
 def test_service_backend_crosses_checkpoints(tmp_path):
@@ -183,8 +159,8 @@ def test_service_elastic_restore_midrun(w_before, w_after, tmp_path):
     results = svc2.drain()
     for i, (family, graph) in enumerate(MIX):
         assert results[i].optimum == ORACLES[i], (i, family, graph.name)
-        assert_valid_payload(family, graph, results[i].payload,
-                             results[i].optimum)
+        assert_feasible(family, graph, results[i].payload,
+                        results[i].optimum)
     assert not svc2.pool                          # pending pool drained
 
 

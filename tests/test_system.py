@@ -1,14 +1,8 @@
-"""End-to-end system tests: examples run, launchers run, serving path
-agrees with training forward, dry-run machinery works in miniature."""
+"""End-to-end system tests: the examples and the solver launchers run."""
 
 import os
 import subprocess
 import sys
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
@@ -26,19 +20,6 @@ def run_script(args, timeout=600):
 def test_quickstart_example():
     out = run_script(["examples/quickstart.py"])
     assert "optimum matches the serial oracle" in out
-
-
-@pytest.mark.slow
-def test_guided_decode_example():
-    out = run_script(["examples/guided_decode.py"])
-    assert "same optimum" in out
-
-
-@pytest.mark.slow
-def test_train_lm_example_short():
-    out = run_script(["examples/train_lm.py", "--steps", "40",
-                      "--batch", "4", "--seq", "128"])
-    assert "improved" in out
 
 
 def test_solver_cli_with_checkpoint(tmp_path):
@@ -66,49 +47,3 @@ def test_serve_solver_cli_smoke():
                       "--lanes", "8", "--slots", "2",
                       "--steps-per-round", "16"])
     assert "drained 2 requests" in out
-
-
-@pytest.mark.slow
-def test_serve_cli_smoke():
-    out = run_script(["-m", "repro.launch.serve", "--arch", "qwen2-7b",
-                      "--smoke", "--batch", "2", "--prompt-len", "16",
-                      "--gen", "4"])
-    assert "decoded 4 tokens" in out
-
-
-@pytest.mark.slow
-def test_kv_quant_matches_bf16_decode():
-    """int8 KV cache must produce near-identical decode logits on the
-    smoke model (quantization noise small vs logit scale)."""
-    from repro import configs
-    from repro.models import model as M
-    from repro.serve.engine import make_decode_step, make_prefill_step
-
-    cfg = configs.smoke("qwen2-7b")
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab)
-    outs = {}
-    for quant in (False, True):
-        prefill = make_prefill_step(cfg, block_q=8, block_k=8,
-                                    kv_quant=quant)
-        decode = make_decode_step(cfg, kv_quant=quant)
-        logits, cache = prefill(params, {"tokens": toks[:, :16]})
-        cache = M.pad_cache(cfg, cache, 24)
-        seq = []
-        for i in range(4):
-            pos = jnp.int32(16 + i)
-            logits, cache = decode(params, cache, toks[:, 16 + i:17 + i],
-                                   pos)
-            seq.append(np.asarray(logits, np.float32))
-        outs[quant] = np.stack(seq)
-    np.testing.assert_allclose(outs[False], outs[True], rtol=0.15,
-                               atol=0.15)
-
-
-@pytest.mark.slow
-def test_dryrun_cell_miniature():
-    """The dry-run module end-to-end on one cheap cell (subprocess: the
-    512-device flag must precede jax init)."""
-    out = run_script(["-m", "repro.launch.dryrun", "--arch", "mamba2-130m",
-                      "--shape", "decode_32k"], timeout=900)
-    assert "[ok]" in out and "dry-run: 1 ok" in out

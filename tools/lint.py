@@ -59,15 +59,13 @@ def main(argv: List[str] = None) -> int:
     errors = result.errors
     warnings = [f for f in result.findings if f.severity != "error"]
     print(f"lint: {result.files} files, {len(errors)} error(s), "
-          f"{len(warnings)} warning(s), "
-          f"{len(result.skipped)} allowlisted file(s) skipped")
+          f"{len(warnings)} warning(s)")
 
     if args.json:
         payload = {
             "files": result.files,
             "errors": len(errors),
             "warnings": len(warnings),
-            "skipped": result.skipped,
             "findings": [
                 {"rule": f.rule, "path": f.path, "line": f.line,
                  "severity": f.severity, "message": f.message}
